@@ -63,7 +63,9 @@ def main() -> None:
 
     from benchmarks import common
     from benchmarks.common import Timer, emit
+    from repro.runtime.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     common.SMOKE = args.smoke
     # --smoke alone runs the CI subset; an explicit selection (positional
     # or --only) picks from ALL modules, with --smoke just thinning the

@@ -219,12 +219,12 @@ def decode_timer(cfg, params, pk, *, reps: int = 30):
     for _ in range(4):                   # admit + warm
         rt.step()
     state, fn, pk2 = rt._state, rt._decode_fn, rt.pack
-    jax.block_until_ready(fn(state, pk2).tok)
+    jax.block_until_ready(fn(state, params, pk2).tok)
 
     def timed() -> float:
         t0 = time.perf_counter()
         for _ in range(reps):
-            out = fn(state, pk2)
+            out = fn(state, params, pk2)
         jax.block_until_ready(out.tok)
         return (time.perf_counter() - t0) / reps * 1e6
 

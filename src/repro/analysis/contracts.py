@@ -153,13 +153,14 @@ class CompileContract:
 
 def _static_findings(c: CompileContract) -> List[Finding]:
     from repro.sweep.executor import compile_groups
-    from repro.sweep.results import point_key
+    from repro.sweep.results import device_tag, point_key
 
     ev = c.evaluator()
     pts = c.sweep.expand()
     proto = c.sweep.point_protocol()
+    dev = device_tag()
     groups = compile_groups(
-        [(point_key(ev.signature(), p, proto), p) for p in pts], ev)
+        [(point_key(ev.signature(), p, proto, dev), p) for p in pts], ev)
     out: List[Finding] = []
     where = f"contract {c.name!r}"
     if c.max_groups is not None and len(groups) > c.max_groups:
@@ -245,7 +246,7 @@ def check_contracts(contracts: Sequence[CompileContract],
 
 def jaxpr_scalar_constants(closed) -> List[float]:
     """Every scalar float constant in a closed jaxpr, sub-jaxprs included."""
-    import jax.core
+    import jax.extend.core as jex_core
 
     out: List[float] = []
 
@@ -257,18 +258,18 @@ def jaxpr_scalar_constants(closed) -> List[float]:
     def visit_jaxpr(jaxpr) -> None:
         for eqn in jaxpr.eqns:
             for var in eqn.invars:
-                if isinstance(var, jax.core.Literal):
+                if isinstance(var, jex_core.Literal):
                     add(var.val)
             for p in eqn.params.values():
                 for sub in _sub_jaxprs(p):
                     visit_jaxpr(sub)
 
     def _sub_jaxprs(p):
-        if isinstance(p, jax.core.ClosedJaxpr):
+        if isinstance(p, jex_core.ClosedJaxpr):
             for cv in p.consts:
                 add(cv)
             yield p.jaxpr
-        elif isinstance(p, jax.core.Jaxpr):
+        elif isinstance(p, jex_core.Jaxpr):
             yield p
         elif isinstance(p, (tuple, list)):
             for item in p:

@@ -9,9 +9,14 @@ extracted *inside* the kernel in VMEM instead of being materialized in HBM
 Grid/BlockSpec layout, both kernels::
 
     grid = (M // bm, N // bn, P)          # P = analog K-partitions
-    x block  (bm, 1, rows)   index (i, p, 0)  -> VMEM
-    g blocks (1, rows, bn)   index (p, 0, j)  -> VMEM
+    x block  (-, bm, rows)   index (p, i, 0)  -> VMEM   x is (P, M, rows)
+    g blocks (-, rows, bn)   index (p, 0, j)  -> VMEM
     out      (bm, bn)        index (i, j)     accumulated over p
+
+(``-`` is a squeezed partition dim.)  x is partition-major so that its
+block's last two dims are ``(bm, rows)``: Mosaic requires the last two
+block dims to be (8, 128)-aligned or whole, and an ``(M, P, rows)``
+layout would put a block dim of 1 over P there.
 
 The innermost grid dimension walks the analog partitions; the output block
 is revisited and accumulated, mirroring the digital partial-sum adder that
@@ -30,8 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import COMPILER_PARAMS
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _adc_epilogue(v, lo, hi, bits: int):
@@ -58,8 +62,8 @@ def _diff_kernel(x_ref, gp_ref, gm_ref, lo_ref, hi_ref, o_ref, *,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[:, 0, :]                     # (bm, rows)
-    g = gp_ref[0] - gm_ref[0]              # (rows, bn) — analog subtraction
+    x = x_ref[...]                         # (bm, rows)
+    g = gp_ref[...] - gm_ref[...]          # (rows, bn) — analog subtraction
     v = jnp.dot(x, g, preferred_element_type=jnp.float32)
     lo = lo_ref[0, 0]
     hi = hi_ref[0, 0]
@@ -77,8 +81,8 @@ def _bitserial_kernel(x_ref, gp_ref, gm_ref, lo_ref, hi_ref, o_ref, *,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[:, 0, :]                     # (bm, rows) integer-valued float
-    g = gp_ref[0] - gm_ref[0]
+    x = x_ref[...]                         # (bm, rows) integer-valued float
+    g = gp_ref[...] - gm_ref[...]
     lo = lo_ref[0, 0]
     hi = hi_ref[0, 0]
     sign = jnp.sign(x)
@@ -94,7 +98,7 @@ def _bitserial_kernel(x_ref, gp_ref, gm_ref, lo_ref, hi_ref, o_ref, *,
 
 def _common_call(kernel, x_parts, g_pos, g_neg, adc_lo, adc_hi, *,
                  bm: int, bn: int, interpret: bool):
-    m, p, rows = x_parts.shape
+    p, m, rows = x_parts.shape
     _, _, n = g_pos.shape
     if m % bm or n % bn:
         raise ValueError(
@@ -106,22 +110,22 @@ def _common_call(kernel, x_parts, g_pos, g_neg, adc_lo, adc_hi, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, 1, rows), lambda i, j, p_: (i, p_, 0)),
-            pl.BlockSpec((1, rows, bn), lambda i, j, p_: (p_, 0, j)),
-            pl.BlockSpec((1, rows, bn), lambda i, j, p_: (p_, 0, j)),
+            pl.BlockSpec((None, bm, rows), lambda i, j, p_: (p_, i, 0)),
+            pl.BlockSpec((None, rows, bn), lambda i, j, p_: (p_, 0, j)),
+            pl.BlockSpec((None, rows, bn), lambda i, j, p_: (p_, 0, j)),
             pl.BlockSpec((1, 1), lambda i, j, p_: (0, 0)),
             pl.BlockSpec((1, 1), lambda i, j, p_: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, p_: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_parts, g_pos, g_neg, lo2, hi2)
 
 
 def analog_mvm_diff_pallas(
-    x_parts: jax.Array,
+    x_parts: jax.Array,    # (P, M, rows) integer-valued
     g_pos: jax.Array,
     g_neg: jax.Array,
     adc_lo: jax.Array,
@@ -131,7 +135,7 @@ def analog_mvm_diff_pallas(
     gain: float,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     kern = functools.partial(_diff_kernel, adc_bits=adc_bits, gain=gain)
     return _common_call(kern, x_parts, g_pos, g_neg, adc_lo, adc_hi,
@@ -139,7 +143,7 @@ def analog_mvm_diff_pallas(
 
 
 def analog_mvm_bitserial_pallas(
-    x_parts: jax.Array,
+    x_parts: jax.Array,    # (P, M, rows) integer-valued signed
     g_pos: jax.Array,
     g_neg: jax.Array,
     adc_lo: jax.Array,
@@ -150,7 +154,7 @@ def analog_mvm_bitserial_pallas(
     gain: float,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     kern = functools.partial(
         _bitserial_kernel, n_bits=n_bits, adc_bits=adc_bits, gain=gain

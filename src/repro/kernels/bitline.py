@@ -30,6 +30,15 @@ Two kernels:
 Grid: (M // bm, N // bn[, P]); K is kept whole inside the kernel
 (K <= 1152 for realistic arrays: x tile 128x1152 f32 = 0.6 MB, g tile
 1152x128 = 0.6 MB).
+
+The sweep reads one array row per step through ref slices (``pl.ds``):
+row ``i`` of ``g`` is a dynamic sublane slice ``(1, bn)``, and the input
+operand arrives *rows-major* (``(K, M)`` / ``(P, rows, M)``), so row ``i``
+of the inputs is also a sublane slice ``(1, bm)``, turned into the
+``(bm, 1)`` column the outer product needs by a transpose.  Mosaic has no
+lowering for ``lax.dynamic_slice`` of a value, nor for a dynamic lane
+offset that it cannot prove 128-aligned, which rules out slicing the
+``(bm, K)`` layout column by column.
 """
 
 from __future__ import annotations
@@ -39,34 +48,34 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import COMPILER_PARAMS
+from repro.kernels.analog_mvm import _bit_plane
 
 
-def _thomas_bottom_current(plane, g, r, *, k: int):
-    """Bottom-node current (bm, bn) of one signed plane through one line
-    stack: Thomas forward sweep over rows; d'_{K-1} IS v_{K-1} since
-    c_{K-1} = 0 in back-substitution, and I = v_{K-1} / r.
+def _thomas_bottom_current(x_row, g_row, r, *, k: int, shape):
+    """Bottom-node current ``shape = (bm, bn)`` of one signed plane
+    through one line stack: Thomas forward sweep over rows; d'_{K-1} IS
+    v_{K-1} since c_{K-1} = 0 in back-substitution, and I = v_{K-1} / r.
+
+    ``x_row(i)`` returns row ``i`` of the signed input plane as a
+    ``(bm, 1)`` column (entries in {-1, 0, +1}) and ``g_row(i)`` row
+    ``i`` of the conductances as ``(1, bn)``.  Kernels read both through
+    ref slices; the ``ref.py`` oracles through ``lax.dynamic_slice`` of
+    values — the recurrence itself is this one function on both sides.
 
     ``g_i * r`` is factored out so that every product feeding an add
     (``gr``, ``rhs``) has an exactly-representable value — ``a_i`` is in
     {0, 1} and ``x_i`` in {-1, 0, +1} — making the sweep FMA-invariant:
     whether LLVM contracts ``a*b + c`` into an FMA or not, the bits come
-    out the same.  That is what lets the fused parasitic kernel
-    (``kernels.fused``) be pinned bitwise against a jnp oracle calling
-    this very function under a different compilation context.
+    out the same.
     """
-    a = jnp.abs(plane)
-    bm = plane.shape[0]
-    bn = g.shape[1]
 
     def body(i, carry):
         c_prev, d_prev = carry                        # (bm, bn)
-        g_i = jax.lax.dynamic_slice(g, (i, 0), (1, bn))      # (1, bn)
-        x_i = jax.lax.dynamic_slice(plane, (0, i), (bm, 1))  # (bm, 1)
-        a_i = jax.lax.dynamic_slice(a, (0, i), (bm, 1))
-        grr = g_i * r                                 # (1, bn)
-        gr = a_i * grr                                # (bm, bn) exact
+        x_i = x_row(i)                                # (bm, 1)
+        grr = g_row(i) * r                            # (1, bn)
+        gr = jnp.abs(x_i) * grr                       # (bm, bn) exact
         rhs = x_i * grr                               # (bm, bn) exact
         base = jnp.where(i == 0, 1.0, 2.0)
         denom = base + gr + c_prev
@@ -74,16 +83,38 @@ def _thomas_bottom_current(plane, g, r, *, k: int):
         d_new = (rhs + d_prev) / denom
         return (c_new, d_new)
 
-    zeros = jnp.zeros((bm, bn), jnp.float32)
+    zeros = jnp.zeros(shape, jnp.float32)
     _, d_last = jax.lax.fori_loop(0, k, body, (zeros, zeros))
     return d_last / r
 
 
-def _bitline_kernel(r_ref, g_ref, x_ref, o_ref, *, k: int):
-    x = x_ref[...]                    # (bm, K) signed plane
-    g = g_ref[...]                    # (K, bn)
-    r = r_ref[0, 0]
-    out = _thomas_bottom_current(x, g, r, k=k)
+def value_rows(plane, g):
+    """``(x_row, g_row)`` readers over in-register values: the oracle
+    side of :func:`_thomas_bottom_current` (``plane`` is ``(bm, K)``)."""
+    bm, bn = plane.shape[0], g.shape[1]
+    return (lambda i: jax.lax.dynamic_slice(plane, (0, i), (bm, 1)),
+            lambda i: jax.lax.dynamic_slice(g, (i, 0), (1, bn)))
+
+
+def plane_rows(xt_ref, b=None):
+    """Row reader over a rows-major ``(K, bm)`` integer input block for
+    :func:`_thomas_bottom_current`: row ``i`` as a ``(bm, 1)`` column,
+    reduced to signed bit plane ``b`` in VMEM (``None`` = the block is
+    already a signed plane)."""
+    def read(i):
+        x_i = xt_ref[pl.ds(i, 1), :].T
+        return x_i if b is None else _bit_plane(jnp.abs(x_i), jnp.sign(x_i), b)
+    return read
+
+
+def ref_rows(g_ref):
+    """Row reader over a ``(K, bn)`` conductance ref: ``(1, bn)``."""
+    return lambda i: g_ref[pl.ds(i, 1), :]
+
+
+def _bitline_kernel(r_ref, g_ref, xt_ref, o_ref, *, k: int):
+    out = _thomas_bottom_current(plane_rows(xt_ref), ref_rows(g_ref),
+                                 r_ref[0, 0], k=k, shape=o_ref.shape)
     o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -94,7 +125,7 @@ def bitline_mvm_pallas(
     *,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Output currents (M, N) under parasitic bit-line resistance."""
     from repro.core.parasitics import parasitics_off
@@ -114,23 +145,23 @@ def bitline_mvm_pallas(
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
             pl.BlockSpec((k, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((k, bm), lambda i, j: (0, i)),   # rows-major x
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(r2, g, x)
+    )(r2, g, x.T)
 
 
-def _parasitic_diff_kernel(r_ref, lo_ref, hi_ref, x_ref, gp_ref, gm_ref,
+def _parasitic_diff_kernel(r_ref, lo_ref, hi_ref, xt_ref, gp_ref, gm_ref,
                            o_ref, *, n_bits: int, adc_bits: int,
                            gain: float, rows: int):
     """Fused parasitic Design-A path: per input bit, Thomas-solve both
     differential lines, analog-accumulate over bits, one ADC per
     partition, digital accumulation over partitions."""
-    from repro.kernels.analog_mvm import _adc_epilogue, _bit_plane
+    from repro.kernels.analog_mvm import _adc_epilogue
 
     p = pl.program_id(2)
 
@@ -138,18 +169,15 @@ def _parasitic_diff_kernel(r_ref, lo_ref, hi_ref, x_ref, gp_ref, gm_ref,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[:, 0, :]                     # (bm, rows) signed integer-valued
-    gp = gp_ref[0]                         # (rows, bn)
-    gm = gm_ref[0]
     r = r_ref[0, 0]
-    sign = jnp.sign(x)
-    mag = jnp.abs(x)
-
-    acc = jnp.zeros((x.shape[0], gp.shape[1]), jnp.float32)
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
     for b in range(n_bits):                # static unroll: n_bits <= 7
-        plane = _bit_plane(mag, sign, b)
-        i_pos = _thomas_bottom_current(plane, gp, r, k=rows)
-        i_neg = _thomas_bottom_current(plane, gm, r, k=rows)
+        i_pos = _thomas_bottom_current(plane_rows(xt_ref, b),
+                                       ref_rows(gp_ref), r, k=rows,
+                                       shape=o_ref.shape)
+        i_neg = _thomas_bottom_current(plane_rows(xt_ref, b),
+                                       ref_rows(gm_ref), r, k=rows,
+                                       shape=o_ref.shape)
         acc += 2.0 ** b * (i_pos - i_neg)  # switched-capacitor bit accum
 
     v_hat = _adc_epilogue(acc, lo_ref[0, 0], hi_ref[0, 0], adc_bits)
@@ -157,7 +185,7 @@ def _parasitic_diff_kernel(r_ref, lo_ref, hi_ref, x_ref, gp_ref, gm_ref,
 
 
 def analog_bitline_diff_pallas(
-    x_parts: jax.Array,    # (M, P, rows) integer-valued signed
+    xt_parts: jax.Array,   # (P, rows, M) integer-valued signed
     g_pos: jax.Array,      # (P, rows, N)
     g_neg: jax.Array,      # (P, rows, N)
     r_hat,                 # scalar (traced or concrete) parasitic level
@@ -169,10 +197,10 @@ def analog_bitline_diff_pallas(
     gain: float,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fused Design-A MVM under parasitic resistance; (M, N) code units."""
-    m, p, rows = x_parts.shape
+    p, rows, m = xt_parts.shape
     _, _, n = g_pos.shape
     if m % bm or n % bn:
         raise ValueError(
@@ -190,13 +218,13 @@ def analog_bitline_diff_pallas(
             pl.BlockSpec((1, 1), lambda i, j, p_: (0, 0)),
             pl.BlockSpec((1, 1), lambda i, j, p_: (0, 0)),
             pl.BlockSpec((1, 1), lambda i, j, p_: (0, 0)),
-            pl.BlockSpec((bm, 1, rows), lambda i, j, p_: (i, p_, 0)),
-            pl.BlockSpec((1, rows, bn), lambda i, j, p_: (p_, 0, j)),
-            pl.BlockSpec((1, rows, bn), lambda i, j, p_: (p_, 0, j)),
+            pl.BlockSpec((None, rows, bm), lambda i, j, p_: (p_, 0, i)),
+            pl.BlockSpec((None, rows, bn), lambda i, j, p_: (p_, 0, j)),
+            pl.BlockSpec((None, rows, bn), lambda i, j, p_: (p_, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, p_: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r2, lo2, hi2, x_parts, g_pos, g_neg)
+    )(r2, lo2, hi2, xt_parts, g_pos, g_neg)

@@ -14,21 +14,42 @@ Grid/BlockSpec layout (both MVM kernels)::
     grid = (M // bm, N // bn, P)           # P = analog K-partitions
     scale    (1, 1)                        # gain * w_scale * x_scale
     lo/hi    (S, 1)                        # per-slice calibrated ADC range
-    x block  (bm, 1, rows)   index (i, p, 0)
+    x block  (-, bm, rows)   index (p, i, 0)   # x is (P, M, rows)
     g blocks (S, 1, rows, bn) index (0, p, 0, j)   # all slices resident
     out      (bm, bn)        index (i, j)  accumulated over p
+
+The activations are *partition-major* ``(P, M, rows)`` (``-`` is the
+squeezed partition dim): Mosaic requires the last two dims of a block to
+be (8, 128)-aligned or whole, so the ``(bm, rows)`` tile must be the
+trailing pair — an ``(M, P, rows)`` layout would put a block dim of 1
+over ``P`` there, which compiles only when ``P == 1``.  The parasitic
+kernel takes the same operand *rows-major*, ``(P, rows, M)`` with block
+``(-, rows, bm)``: its Thomas sweep reads one array row per step as a
+sublane slice (``kernels.bitline``).  The ``ops`` wrappers take
+``(M, P, rows)`` like every other MVM wrapper and make the one transpose
+their kernel needs.
+
+All ``S`` slices of a partition are resident at once.  At ``S = 8``
+(1-bit cells) and ``rows = 1152`` the two conductance blocks take
+2 x 2 buffers x 4.7 MB = 18.9 MB of VMEM, over the 16 MiB default scoped
+limit, so the MVM kernels raise the limit to :data:`VMEM_LIMIT_BYTES`.
 
 Slices and input bits are *static unrolled* loops inside one grid step
 (S <= 8, n_bits <= 7), so a sliced design still costs one launch; the
 innermost grid dimension walks partitions and revisits the output block,
 and the final partition's step applies the single dequant multiply.
 
-Bitwise contract
-----------------
-Every kernel here is pinned ``array_equal`` against its ``ref.py`` oracle
-(``tests/test_kernels.py``).  Two disciplines make that hold across
-compilation contexts (the oracle compiles inside an arbitrary XLA fusion;
-the kernel lowers through interpret mode on CPU and Mosaic on TPU):
+Exactness contract
+------------------
+Every kernel here is pinned against its ``ref.py`` oracle
+(``tests/test_kernels.py``, ``tests/test_fastpath_routing.py``): bit for
+bit where one partition and one slice leave nothing to reassociate, and
+within a stated fraction of one ADC code otherwise — the float32 bits of
+the partition sum and the dequant multiply follow each compiler's
+contraction choices (2 ULP under XLA-CPU in jax 0.9).  Two disciplines
+keep the two sides on the same ADC codes across compilation contexts
+(the oracle compiles inside an arbitrary XLA fusion; the kernel lowers
+through interpret mode on CPU and Mosaic on TPU):
 
 * *Exact-product multiply-adds only.*  LLVM may contract ``a + b*c`` into
   an FMA (one rounding instead of two) depending on the surrounding
@@ -72,9 +93,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.analog_mvm import _bit_plane
-from repro.kernels.bitline import _thomas_bottom_current
-from repro.kernels.compat import COMPILER_PARAMS
+from repro.kernels.bitline import _thomas_bottom_current, plane_rows
 from repro.kernels.paged import NEG_INF
+
+#: scoped-VMEM limit of the fused MVM kernels.  The largest working set
+#: they are built for is S = 8 slices at rows = 1152 with bm = bn = 128
+#: and digital input accumulation: 18.9 MB of double-buffered
+#: conductance blocks and 1.2 MB of x blocks, plus the seven
+#: (bm, rows) input bit planes and per-slice temporaries of the unrolled
+#: loops.  Mosaic refuses that kernel at 32 MiB and accepts it at 40 MiB
+#: (``tests/test_tpu_compile.py``); 48 MiB leaves a fifth to spare and is
+#: under half of a TPU v5e core's 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 48 * 2 ** 20
 
 
 #: static compile identities of every fused MVM kernel traced in this
@@ -135,7 +165,7 @@ def _fused_diff_kernel(scale_ref, lo_ref, hi_ref, x_ref, gp_ref, gm_ref,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[:, 0, :]                       # (bm, rows) integer-valued
+    x = x_ref[...]                           # (bm, rows) integer-valued
     n_slices = gp_ref.shape[0]
     if n_bits is not None:
         sign = jnp.sign(x)
@@ -170,12 +200,13 @@ def _fused_diff_kernel(scale_ref, lo_ref, hi_ref, x_ref, gp_ref, gm_ref,
         o_ref[...] = o_ref[...] * out_scale
 
 
-def _fused_parasitic_kernel(scale_ref, r_ref, lo_ref, hi_ref, x_ref,
+def _fused_parasitic_kernel(scale_ref, r_ref, lo_ref, hi_ref, xt_ref,
                             gp_ref, gm_ref, o_ref, *, adc_bits: int,
                             cell_bits: int, n_bits: int, rows: int):
     """Design-A parasitic chain: per (slice, bit) Thomas solves for both
     differential lines, analog (switched-capacitor) accumulation over
-    bits, one ADC per slice, shift-and-add, final dequant multiply."""
+    bits, one ADC per slice, shift-and-add, final dequant multiply.
+    ``xt_ref`` is the rows-major ``(rows, bm)`` input block."""
     p = pl.program_id(2)
     n_p = pl.num_programs(2)
 
@@ -183,20 +214,20 @@ def _fused_parasitic_kernel(scale_ref, r_ref, lo_ref, hi_ref, x_ref,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[:, 0, :]                       # (bm, rows) integer-valued
     r = r_ref[0, 0]
-    sign = jnp.sign(x)
-    mag = jnp.abs(x)
     n_slices = gp_ref.shape[0]
     acc = jnp.zeros_like(o_ref)
     for s in range(n_slices):                # static unroll: S <= 8
-        gp = gp_ref[s, 0]                    # (rows, bn)
-        gm = gm_ref[s, 0]
         accb = jnp.zeros_like(o_ref)
         for b in range(n_bits):              # static unroll: n_bits <= 7
-            plane = _bit_plane(mag, sign, b)
-            i_pos = _thomas_bottom_current(plane, gp, r, k=rows)
-            i_neg = _thomas_bottom_current(plane, gm, r, k=rows)
+            i_pos = _thomas_bottom_current(
+                plane_rows(xt_ref, b),
+                lambda i, s=s: gp_ref[s, 0, pl.ds(i, 1), :], r,
+                k=rows, shape=o_ref.shape)
+            i_neg = _thomas_bottom_current(
+                plane_rows(xt_ref, b),
+                lambda i, s=s: gm_ref[s, 0, pl.ds(i, 1), :], r,
+                k=rows, shape=o_ref.shape)
             accb = accb + (i_pos - i_neg) * 2.0 ** b
         lo = lo_ref[s, 0]
         lsb = adc_lsb(lo, hi_ref[s, 0], adc_bits)
@@ -216,15 +247,16 @@ def _fused_parasitic_kernel(scale_ref, r_ref, lo_ref, hi_ref, x_ref,
         o_ref[...] = o_ref[...] * out_scale
 
 
-def _mvm_call(kern, x_parts, g_pos, g_neg, extra, adc_lo, adc_hi, scale, *,
-              bm: int, bn: int, interpret: bool):
+def _mvm_call(kern, x_in, x_spec, g_pos, g_neg, extra, adc_lo, adc_hi,
+              scale, *, m: int, bm: int, bn: int, interpret: bool):
     """Shared pallas_call plumbing for both fused MVM kernels.
 
-    ``extra`` is a list of additional leading (1, 1) scalar operands
-    (the parasitic ``r_hat``); ``adc_lo/adc_hi`` are per-slice (S,).
+    ``x_in`` is the activation operand in the layout its kernel reads,
+    ``x_spec`` its block spec and ``m`` its (padded) row count.
+    ``extra`` is a list of additional leading (1, 1) scalar operands (the
+    parasitic ``r_hat``); ``adc_lo/adc_hi`` are per-slice (S,).
     """
-    m, p, rows = x_parts.shape
-    n_slices, _, _, n = g_pos.shape
+    n_slices, p, rows, n = g_pos.shape
     if m % bm or n % bn:
         raise ValueError(
             f"block shape ({bm}, {bn}) does not tile operand ({m}, {n})")
@@ -242,21 +274,18 @@ def _mvm_call(kern, x_parts, g_pos, g_neg, extra, adc_lo, adc_hi, scale, *,
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=scalar_specs + slice_specs + [
-            pl.BlockSpec((bm, 1, rows), lambda i, j, p_: (i, p_, 0)),
-            g_spec,
-            g_spec,
-        ],
+        in_specs=scalar_specs + slice_specs + [x_spec, g_spec, g_spec],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, p_: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(scale2, *extra2, lo2, hi2, x_parts, g_pos, g_neg)
+    )(scale2, *extra2, lo2, hi2, x_in, g_pos, g_neg)
 
 
 def fused_mvm_pallas(
-    x_parts: jax.Array,    # (M, P, rows) integer-valued signed
+    x_parts: jax.Array,    # (P, M, rows) integer-valued signed
     g_pos: jax.Array,      # (S, P, rows, N)
     g_neg: jax.Array,      # (S, P, rows, N)
     adc_lo: jax.Array,     # (S,) per-slice calibrated range
@@ -268,19 +297,21 @@ def fused_mvm_pallas(
     n_bits,                # None = analog input accumulation
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fused differential analog MVM; returns the dequantized (M, N)."""
     BUILD_SIGNATURES.add(("linear", g_pos.shape[0], cell_bits, adc_bits,
                           n_bits, None))
     kern = functools.partial(_fused_diff_kernel, adc_bits=adc_bits,
                              cell_bits=cell_bits, n_bits=n_bits)
-    return _mvm_call(kern, x_parts, g_pos, g_neg, [], adc_lo, adc_hi,
-                     scale, bm=bm, bn=bn, interpret=interpret)
+    _, m, rows = x_parts.shape
+    x_spec = pl.BlockSpec((None, bm, rows), lambda i, j, p_: (p_, i, 0))
+    return _mvm_call(kern, x_parts, x_spec, g_pos, g_neg, [], adc_lo,
+                     adc_hi, scale, m=m, bm=bm, bn=bn, interpret=interpret)
 
 
 def fused_mvm_parasitic_pallas(
-    x_parts: jax.Array,    # (M, P, rows) integer-valued signed
+    xt_parts: jax.Array,   # (P, rows, M) integer-valued signed
     g_pos: jax.Array,      # (S, P, rows, N)
     g_neg: jax.Array,      # (S, P, rows, N)
     r_hat,                 # traced or concrete scalar parasitic level
@@ -293,16 +324,17 @@ def fused_mvm_parasitic_pallas(
     n_bits: int,
     bm: int = 128,
     bn: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fused parasitic differential MVM; returns the dequantized (M, N)."""
     BUILD_SIGNATURES.add(("parasitic", g_pos.shape[0], cell_bits, adc_bits,
                           None, n_bits))
-    rows = x_parts.shape[-1]
+    _, rows, m = xt_parts.shape
     kern = functools.partial(_fused_parasitic_kernel, adc_bits=adc_bits,
                              cell_bits=cell_bits, n_bits=n_bits, rows=rows)
-    return _mvm_call(kern, x_parts, g_pos, g_neg, [r_hat], adc_lo, adc_hi,
-                     scale, bm=bm, bn=bn, interpret=interpret)
+    x_spec = pl.BlockSpec((None, rows, bm), lambda i, j, p_: (p_, 0, i))
+    return _mvm_call(kern, xt_parts, x_spec, g_pos, g_neg, [r_hat], adc_lo,
+                     adc_hi, scale, m=m, bm=bm, bn=bn, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -369,7 +401,7 @@ def flash_attention_pallas(
     *,
     block: int,
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, h, hd = q.shape
     _, seq, kv_heads, _ = k.shape

@@ -79,7 +79,7 @@ def analog_mvm(
     n = g_pos.shape[-1]
     bm = _pick_tile(m, 128)
     bn = _pick_tile(n, 128, lane=True)
-    xp = _pad_to(x_parts.astype(jnp.float32), 0, bm)
+    xp = _pad_to(jnp.swapaxes(x_parts, 0, 1).astype(jnp.float32), 1, bm)
     gp = _pad_to(g_pos.astype(jnp.float32), 2, bn)
     gm = _pad_to(g_neg.astype(jnp.float32), 2, bn)
     out = _k_mvm.analog_mvm_diff_pallas(
@@ -111,7 +111,7 @@ def analog_mvm_bitserial(
     n = g_pos.shape[-1]
     bm = _pick_tile(m, 128)
     bn = _pick_tile(n, 128, lane=True)
-    xp = _pad_to(x_parts.astype(jnp.float32), 0, bm)
+    xp = _pad_to(jnp.swapaxes(x_parts, 0, 1).astype(jnp.float32), 1, bm)
     gp = _pad_to(g_pos.astype(jnp.float32), 2, bn)
     gm = _pad_to(g_neg.astype(jnp.float32), 2, bn)
     out = _k_mvm.analog_mvm_bitserial_pallas(
@@ -199,6 +199,8 @@ def fused_mvm(
 ) -> jax.Array:
     """Fused analog MVM chain (matmul + ADC + dequant + slice/bit
     shift-and-add in one launch); returns the dequantized (M, N).
+    The kernel and its oracle read the activations partition-major
+    ``(P, M, rows)`` (see ``kernels.fused``).
 
     ``backend="kernel"`` runs the Pallas kernel (interpret mode off-TPU),
     ``"oracle"`` the bitwise-identical jnp mirror (``kernels.ref``) —
@@ -212,7 +214,7 @@ def fused_mvm(
     n = g_pos.shape[-1]
     bm = _pick_tile(m, 128)
     bn = _pick_tile(n, 128, lane=True)
-    xp = _pad_to(x_parts.astype(jnp.float32), 0, bm)
+    xp = _pad_to(jnp.swapaxes(x_parts, 0, 1).astype(jnp.float32), 1, bm)
     gp = _pad_to(g_pos.astype(jnp.float32), 3, bn)
     gm = _pad_to(g_neg.astype(jnp.float32), 3, bn)
     if backend == "oracle":
@@ -246,7 +248,9 @@ def fused_mvm_parasitic(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused parasitic analog MVM chain (per-bit Thomas solve + analog
-    bit accumulation + ADC + dequant in one launch); dequantized (M, N)."""
+    bit accumulation + ADC + dequant in one launch); dequantized (M, N).
+    The kernel and its oracle read the activations rows-major
+    ``(P, rows, M)`` (see ``kernels.bitline``)."""
     if backend not in ("kernel", "oracle"):
         raise ValueError(f"unknown fused_mvm_parasitic backend {backend!r}")
     interpret = _use_interpret() if interpret is None else interpret
@@ -254,18 +258,18 @@ def fused_mvm_parasitic(
     n = g_pos.shape[-1]
     bm = _pick_tile(m, 128)
     bn = _pick_tile(n, 128, lane=True)
-    xp = _pad_to(x_parts.astype(jnp.float32), 0, bm)
+    xt = _pad_to(jnp.transpose(x_parts, (1, 2, 0)).astype(jnp.float32), 2, bm)
     gp = _pad_to(g_pos.astype(jnp.float32), 3, bn)
     gm = _pad_to(g_neg.astype(jnp.float32), 3, bn)
     if backend == "oracle":
         out = _k_ref.fused_mvm_parasitic(
-            xp, gp, gm, r_hat, adc_lo, adc_hi, scale,
+            xt, gp, gm, r_hat, adc_lo, adc_hi, scale,
             adc_bits=adc_bits, cell_bits=cell_bits, n_bits=n_bits,
             bm=bm, bn=bn,
         )
     else:
         out = _k_fused.fused_mvm_parasitic_pallas(
-            xp, gp, gm, r_hat, adc_lo, adc_hi, scale,
+            xt, gp, gm, r_hat, adc_lo, adc_hi, scale,
             adc_bits=adc_bits, cell_bits=cell_bits, n_bits=n_bits,
             bm=bm, bn=bn, interpret=interpret,
         )
@@ -385,11 +389,11 @@ def analog_mvm_parasitic(
     n = g_pos.shape[-1]
     bm = _pick_tile(m, 128)
     bn = _pick_tile(n, 128, lane=True)
-    xp = _pad_to(x_parts.astype(jnp.float32), 0, bm)
+    xt = _pad_to(jnp.transpose(x_parts, (1, 2, 0)).astype(jnp.float32), 2, bm)
     gp = _pad_to(g_pos.astype(jnp.float32), 2, bn)
     gm = _pad_to(g_neg.astype(jnp.float32), 2, bn)
     out = _k_bl.analog_bitline_diff_pallas(
-        xp, gp, gm, r_hat,
+        xt, gp, gm, r_hat,
         jnp.asarray(adc_lo), jnp.asarray(adc_hi),
         n_bits=n_bits, adc_bits=adc_bits, gain=float(gain),
         bm=bm, bn=bn, interpret=interpret,

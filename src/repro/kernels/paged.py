@@ -32,9 +32,11 @@ a 1-ulp drift).  Materializing every page's term first forces each
 product through a loop-carried scratch buffer, where it must be a
 rounded f32 before the phase-2 add ever sees it; the accumulation is
 then a plain add of identically-computed terms in page order, so the
-kernel is *bit-exact* against ``ref.paged_attention_decode`` (pinned
-with ``array_equal`` in ``tests/test_kernels.py``), at the cost of
-streaming K twice and ``NP`` per-page term slots of VMEM scratch.
+kernel matches ``ref.paged_attention_decode`` bit for bit on most inputs
+and within a few ULPs of max|v| on all (``tests/test_kernels.py``: the
+score dot and ``exp`` still round per fusion context under XLA-CPU), at
+the cost of streaming K twice and ``NP`` per-page term slots of VMEM
+scratch.
 
 Per-row cache lengths arrive as the second scalar-prefetch operand;
 positions at or beyond ``kv_len[b]`` are masked to ``NEG_INF`` exactly
@@ -116,7 +118,7 @@ def paged_attention_pallas(
     kv_len: jax.Array,     # (B,) int32 valid positions per row
     *,
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, h, hd = q.shape
     _, page_size, kv_heads, _ = k_pages.shape

@@ -10,13 +10,10 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; older versions default to
-    Auto semantics anyway, so omit the argument there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    """GSPMD (``Auto``) semantics on every axis; ``jax.make_mesh`` would
+    default to ``Explicit``."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,13 +25,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for the 8-device subprocess tests."""
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **_mesh_kwargs(2))
+                         axis_types=_auto(2))
 
 
 def dp_axes(mesh) -> tuple:

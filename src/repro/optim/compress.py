@@ -50,19 +50,6 @@ def init_residual(params) -> Any:
     return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
 
 
-def _axis_size(axis_name) -> int:
-    """Static size of a named mesh axis inside shard_map.
-
-    jax >= 0.5 spells this ``lax.axis_size``; 0.4.x exposes it as
-    ``jax.core.axis_frame`` (which returns the size directly on 0.4.37,
-    a frame object with ``.size`` on some adjacent versions).
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return getattr(frame, "size", frame)
-
-
 def ring_allreduce_int8(q: jax.Array, scale: jax.Array, axis_name: str):
     """Ring all-reduce of an int8 payload inside shard_map.
 
@@ -70,7 +57,7 @@ def ring_allreduce_int8(q: jax.Array, scale: jax.Array, axis_name: str):
     int8 + one fp32 scale; the accumulator is requantized after each add,
     bounding wire format at 8 bits everywhere.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     # The int8 payload rotates around the ring *unchanged* (each rank's
     # original contribution visits every rank); the accumulator is local

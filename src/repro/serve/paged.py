@@ -266,7 +266,8 @@ class PagedServeRuntime(ServeRuntime):
         if fn is None:
             fn = self._prefill_fns[fnkey] = jax.jit(
                 self._make_paged_prefill_fn())
-        self._state = fn(self._state, self.pack, jnp.asarray(suffix),
+        self._state = fn(self._state, self.params, self.pack,
+                         jnp.asarray(suffix),
                          jnp.asarray(true_lens), jnp.asarray(slots),
                          jnp.asarray(max_new), jnp.stack(keys),
                          jnp.asarray(ctx_pages), jnp.asarray(ptabg))
@@ -280,12 +281,12 @@ class PagedServeRuntime(ServeRuntime):
             self._stats["ttft_s"].append(req.ttft_s)
 
     def _make_paged_prefill_fn(self):
-        cfg, params = self.cfg, self.params
+        cfg = self.cfg
         api, sampler, eos = self._api, self.sampler, self._eos
         ps, npg = self.page_size, self._np
 
-        def prefill(state: SlotState, pack, suffix, true_lens, slots,
-                    max_new, keys, ctx_pages, ptabg) -> SlotState:
+        def prefill(state: SlotState, params, pack, suffix, true_lens,
+                    slots, max_new, keys, ctx_pages, ptabg) -> SlotState:
             g, s = suffix.shape
             ncp = ctx_pages.shape[1]
             ctx = ncp * ps
@@ -351,14 +352,14 @@ class PagedServeRuntime(ServeRuntime):
     def _run_decode(self) -> None:
         # the block table is traced data: admissions rewrite it without
         # recompiling the step (repro.analysis contract "paged-decode")
-        self._state = self._decode_fn(self._state, self.pack,
+        self._state = self._decode_fn(self._state, self.params, self.pack,
                                       jnp.asarray(self._ptab))
 
     def _make_decode_model(self):
-        cfg, params, api = self.cfg, self.params, self._api
+        cfg, api = self.cfg, self._api
         backend = self.backend
 
-        def model(state: SlotState, pack, ptab):
+        def model(state: SlotState, params, pack, ptab):
             cache = {"pool": state.layers, "ptab": ptab,
                      "len": state.length}
             logits, cache = api.decode_step_paged(

@@ -439,7 +439,7 @@ class ServeRuntime:
         """Dispatch one jitted decode step over the slot state.  Hook for
         subclasses that thread extra traced operands through the step
         (the paged runtime passes its block table)."""
-        self._state = self._decode_fn(self._state, self.pack)
+        self._state = self._decode_fn(self._state, self.params, self.pack)
 
     def _maintain(self) -> None:
         """Device-state upkeep between decode steps (no-op without a
@@ -587,7 +587,8 @@ class ServeRuntime:
         if fn is None:
             fn = self._prefill_fns[(bucket, g)] = jax.jit(
                 self._make_prefill_fn())
-        self._state = fn(self._state, self.pack, jnp.asarray(prompts),
+        self._state = fn(self._state, self.params, self.pack,
+                         jnp.asarray(prompts),
                          jnp.asarray(true_lens), jnp.asarray(slots),
                          jnp.asarray(max_new), jnp.stack(keys))
         self._stats["prefill_calls"] += 1
@@ -640,15 +641,16 @@ class ServeRuntime:
     # -- jitted step bodies ------------------------------------------------
 
     def _make_decode_model(self):
-        """The model half of the decode step: (state, pack, *extra) ->
-        (last-token logits, new cache layers, new lengths).  Hook — the
-        paged runtime swaps in ``decode_step_paged`` over the page pool;
-        the sampling/bookkeeping tail in ``_make_decode_fn`` is shared.
+        """The model half of the decode step: (state, params, pack,
+        *extra) -> (last-token logits, new cache layers, new lengths).
+        Hook — the paged runtime swaps in ``decode_step_paged`` over the
+        page pool; the sampling/bookkeeping tail in ``_make_decode_fn``
+        is shared.
         """
-        cfg, params, api = self.cfg, self.params, self._api
+        cfg, api = self.cfg, self._api
         attn_backend = self.attn_backend
 
-        def model(state: SlotState, pack):
+        def model(state: SlotState, params, pack):
             cache = {"layers": state.layers, "len": state.length}
             logits, cache = api.decode_step(
                 cfg, params, state.tok[:, None], cache, pack=pack,
@@ -661,11 +663,13 @@ class ServeRuntime:
         sampler, eos = self.sampler, self._eos
         model = self._make_decode_model()
 
-        # the pack is a traced ARGUMENT, not a closure: a healed/aged pack
-        # (same treedef, new conductances) swaps in between decode steps
-        # without recompiling the step
-        def decode(state: SlotState, pack, *extra) -> SlotState:
-            logits, layers, length = model(state, pack, *extra)
+        # params and pack are traced ARGUMENTS, not closures: a healed/
+        # aged pack (same treedef, new conductances) swaps in between
+        # decode steps without recompiling the step, and no weight is
+        # baked into the executable as a constant (at published widths
+        # that would copy gigabytes into every compiled program)
+        def decode(state: SlotState, params, pack, *extra) -> SlotState:
+            logits, layers, length = model(state, params, pack, *extra)
             nxt, keys = sample_tokens(logits, state.key, sampler)
             act = state.active
             cap = state.out.shape[1]
@@ -688,11 +692,11 @@ class ServeRuntime:
         return decode
 
     def _make_prefill_fn(self):
-        cfg, params = self.cfg, self.params
+        cfg = self.cfg
         api, sampler, eos = self._api, self.sampler, self._eos
 
-        def prefill(state: SlotState, pack, prompts, true_lens, slots,
-                    max_new, keys) -> SlotState:
+        def prefill(state: SlotState, params, pack, prompts, true_lens,
+                    slots, max_new, keys) -> SlotState:
             logits, pcache = api.prefill_ragged(
                 cfg, params, prompts, true_lens=true_lens, pack=pack)
             first, keys = sample_tokens(logits[:, -1], keys, sampler)

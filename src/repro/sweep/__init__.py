@@ -28,7 +28,13 @@ from repro.sweep.evaluate import (
     trial_keys,
 )
 from repro.sweep.executor import compile_groups, run_sweep
-from repro.sweep.results import PointResult, SweepCache, SweepResults, point_key
+from repro.sweep.results import (
+    PointResult,
+    SweepCache,
+    SweepResults,
+    device_tag,
+    point_key,
+)
 from repro.sweep.serve_eval import ServeEvaluator, serve_serial_reference
 from repro.sweep.spec import Axis, DesignPoint, SweepSpec, get_field, set_field
 
@@ -43,6 +49,7 @@ __all__ = [
     "SweepResults",
     "SweepSpec",
     "compile_groups",
+    "device_tag",
     "get_field",
     "mapping_signature",
     "materialize",

@@ -23,11 +23,18 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def sweep_mesh() -> Optional[jax.sharding.Mesh]:
-    """1-D ``data`` mesh over all local devices; None when single-device."""
+    """1-D ``data`` mesh over all local devices; None when single-device.
+
+    The axis is ``Auto``: GSPMD propagates the batch sharding through the
+    unchanged evaluator.  (``jax.make_mesh`` defaults to ``Explicit``
+    axes, whose shardings enter every aval and which the evaluator's
+    scans reject outside a ``jax.set_mesh`` context.)
+    """
     devs = jax.devices()
     if len(devs) < 2:
         return None
-    return jax.make_mesh((len(devs),), ("data",))
+    return jax.make_mesh((len(devs),), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def shard_leading(arr: jax.Array, mesh: Optional[jax.sharding.Mesh],

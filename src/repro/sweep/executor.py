@@ -4,8 +4,8 @@ Execution model (DESIGN.md §Sweep-engine):
 
 1. **Expand** the :class:`~repro.sweep.spec.SweepSpec` grid into the flat
    design-point table.
-2. **Resume**: points whose ``(evaluator signature, spec, protocol)``
-   hash is already in the on-disk :class:`~repro.sweep.results.SweepCache`
+2. **Resume**: points whose ``(evaluator signature, spec, protocol,
+   device)`` hash is already in the on-disk :class:`~repro.sweep.results.SweepCache`
    are returned without recomputation.
 3. **Group** the remaining points by *compile signature* — the spec with
    the evaluator's dynamic scalar fields (error magnitude, On/Off ratio)
@@ -33,6 +33,7 @@ from repro.sweep.results import (
     PointResult,
     SweepCache,
     SweepResults,
+    device_tag,
     point_key,
 )
 from repro.sweep.spec import DesignPoint, SweepSpec, set_field
@@ -102,12 +103,13 @@ def run_sweep(
     points = sweep.expand()
     protocol = sweep.point_protocol()
     sig = evaluator.signature()
+    device = device_tag()
     cache = SweepCache(cache_dir, sweep.name) if cache_dir else None
 
     results: List[PointResult] = []
     pending: List[Tuple[str, DesignPoint]] = []
     for pt in points:
-        key = point_key(sig, pt, protocol)
+        key = point_key(sig, pt, protocol, device)
         hit = cache.get(key) if (cache and not force) else None
         if hit is not None:
             results.append(

@@ -3,10 +3,12 @@
 Every evaluated design point becomes a :class:`PointResult`; a sweep's
 results persist as one JSON file per sweep name (default under
 ``benchmarks/_cache/sweeps``), keyed by a content hash of
-``(evaluator signature, spec repr, trial protocol)``.  Re-running a sweep
-— after a crash, an added axis value, or on another host with the cache
-directory synced — recomputes only the missing points (the same
-resumability contract as ``repro.launch.dryrun``'s result files).
+``(evaluator signature, spec repr, trial protocol, device)``.  Re-running
+a sweep — after a crash, an added axis value, or on another host with the
+cache directory synced — recomputes only the missing points (the same
+resumability contract as ``repro.launch.dryrun``'s result files).  The
+device (:func:`device_tag`) is part of the key, so a run on a TPU never
+reads back a result computed on the CPU, or the other way round.
 """
 
 from __future__ import annotations
@@ -22,15 +24,26 @@ from typing import Any, Dict, List, Optional
 from repro.sweep.spec import DesignPoint, SweepSpec
 
 
-def point_key(evaluator_sig: str, point: DesignPoint, protocol: str) -> str:
-    """Stable cache identity of one evaluated design point.
+def device_tag() -> str:
+    """``platform/device_kind`` of the default device, e.g. ``cpu/cpu``
+    or ``tpu/TPU v5 lite``: the device part of every cache key."""
+    import jax
+
+    d = jax.devices()[0]
+    return f"{d.platform}/{d.device_kind}"
+
+
+def point_key(evaluator_sig: str, point: DesignPoint, protocol: str,
+              device: str) -> str:
+    """Stable cache identity of one evaluated design point on ``device``
+    (:func:`device_tag`).
 
     ``repr`` of an :class:`~repro.core.analog.AnalogSpec` is deterministic
     (frozen dataclasses of primitives), so the hash covers every static
     field of the design point plus the weights/data hash carried in the
     evaluator signature.
     """
-    blob = "\n".join([evaluator_sig, repr(point.spec), protocol])
+    blob = "\n".join([evaluator_sig, repr(point.spec), protocol, device])
     return hashlib.sha256(blob.encode()).hexdigest()[:24]
 
 

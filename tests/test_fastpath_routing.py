@@ -5,12 +5,17 @@ partitions; the must-refuse-to-fuse fallbacks; and the
 ``fuse_signature`` compile identity the per-site-class serving contract
 keys on (``repro.hw.fused_site_classes``).
 
-Exactness policy: the serving decode path is jitted, and under jit the
-fused kernel is BITWISE equal to its jnp oracle — that equality is what
-the runtime's token-agreement contract rests on, so it is pinned with
-``array_equal`` here.  Eagerly, XLA dispatches the chain as separate
-ops and may contract the final dequant multiply differently (a 1-2 ULP
-artifact, never an ADC code flip), so eager checks use float tolerance.
+Exactness policy: kernel and oracle compute the same ADC codes in the
+same tile order, but the float32 bits of the partition sum and the final
+dequant multiply depend on how each side's compiler contracts and
+fuses them, which shifts between XLA releases and backends (a
+multi-partition design drifts by 2 ULP under XLA-CPU in jax 0.9).  So
+under jit the kernel is pinned to the oracle within a small fraction of
+one ADC code (:func:`_assert_within_codes`), far below the one-code
+step of any real disagreement.  The bitwise pin is kept where the bits
+are structural: a refused design's fallback IS the composed chain.
+Eagerly, XLA dispatches the chain as separate ops (a 1-2 ULP artifact,
+never an ADC code flip), so eager checks use float tolerance.
 Fused-vs-composed compares two *different* op orders over the same ADC
 codes: float-level agreement, not bitwise.
 """
@@ -62,6 +67,32 @@ REFUSED = [
 ]
 
 
+#: kernel-vs-oracle bound, in ADC codes of the finest slice.  Both sides
+#: hold identical codes, so what is left is float32 reassociation of the
+#: partition sum and the dequant multiply: measured at 4.4e-5 codes
+#: (2 ULP) for the multi-partition design.  1e-3 codes is 20x that and
+#: still 1000x below a single code flip, the signature of a real
+#: kernel/oracle disagreement.
+MAX_CODE_DRIFT = 1e-3
+
+
+def _code_step(spec, aw, x, lo, hi):
+    """Output-space size of one ADC code of the finest slice:
+    ``gain * w_scale * x_scale * lsb``."""
+    from repro.core.quant import quantize_acts
+
+    m = spec.mapping
+    gain = (m.levels_per_cell - 1) / (1.0 - m.g_min)
+    x_scale = quantize_acts(x.astype(jnp.float32), spec.input_bits).scale
+    lsb = jnp.min((hi - lo) / (2 ** spec.adc.bits - 1))
+    return float(gain * aw.w_scale * x_scale * lsb)
+
+
+def _assert_within_codes(y_k, y_o, step, codes=MAX_CODE_DRIFT):
+    d = np.abs(np.asarray(y_k) - np.asarray(y_o)) / step
+    assert float(d.max()) <= codes, f"max drift {float(d.max()):.3e} codes"
+
+
 def _case(spec, m=4, k=200, n=48, seed=0):
     kw_, kx = jax.random.split(jax.random.PRNGKey(seed))
     w = jax.random.normal(kw_, (k, n)) * 0.1
@@ -89,7 +120,7 @@ def test_fused_routes_and_agrees(tag, spec, sig):
         for mode in ("kernel", "oracle")
     }
     y_k, y_o = arms["kernel"](x), arms["oracle"](x)
-    np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_o))
+    _assert_within_codes(y_k, y_o, _code_step(spec, aw, x, lo, hi))
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_c),
                                rtol=2e-5, atol=1e-5)
 

@@ -336,18 +336,31 @@ def _paged_case(b, h, kv, hd, ps, np_pages, seed=0):
     return q, k_pages, v_pages, jnp.asarray(ptab), jnp.asarray(kv_len)
 
 
+#: paged kernel-vs-oracle bound, in float32 ULPs of max|v|.  Each
+#: output is a softmax-weighted mean of v rows (weights sum to 1), and
+#: both sides take the same two-phase steps, so any difference is how
+#: XLA-CPU contracts or vectorizes the score dot and ``exp`` in each
+#: fusion context: measured at most 1 ULP of max|v| over 12 seeds of
+#: every shape below (jax 0.9).  A leaked masked position or a wrong
+#: running max moves an output by ~max|v| / n_positions, ~1e6 ULPs.
+PAGED_ULPS_OF_VMAX = 4
+
+
 @pytest.mark.parametrize("b,h,kv,hd,ps,np_pages", PAGED_SHAPES)
 def test_paged_attention_bit_exact_vs_oracle(b, h, kv, hd, ps, np_pages):
-    """The two-phase kernel is BITWISE equal to the two-phase jnp
-    oracle — the exactness anchor of the paged serving runtime (see
-    kernels/paged.py on why one-pass online softmax cannot give this:
-    FMA contraction of the rescale differs across compilation
-    contexts)."""
+    """The two-phase kernel matches the two-phase jnp oracle to a few
+    ULPs of the value scale (see kernels/paged.py on why one-pass online
+    softmax cannot: its rescale reassociates per compilation context).
+    Most shapes agree bit for bit; the bits themselves are not
+    structural — XLA-CPU's contraction choices inside the oracle's
+    fusions move them by an ULP for some inputs — so the pin is the
+    stated bound :data:`PAGED_ULPS_OF_VMAX`."""
     args = _paged_case(b, h, kv, hd, ps, np_pages)
-    out = ops.paged_attention(*args)
-    want = ref.paged_attention_decode(*args)
+    out = np.asarray(ops.paged_attention(*args))
+    want = np.asarray(ref.paged_attention_decode(*args))
     assert out.dtype == want.dtype and out.shape == (b, h, hd)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    ulp = np.spacing(np.abs(np.asarray(args[2])).max())
+    assert float(np.abs(out - want).max()) <= PAGED_ULPS_OF_VMAX * ulp
 
 
 def test_paged_attention_invariant_to_table_tail_padding():

@@ -243,6 +243,42 @@ def test_eos_during_reprogram_race(lm, noop_aging_manager):
 # ---------------------------------------------------------------------------
 
 
+def _captured_consts(closed):
+    """Every array a jaxpr (and its sub-jaxprs) closes over."""
+    import jax.extend.core as jex_core
+
+    out = list(closed.consts)
+    for eqn in closed.jaxpr.eqns:
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    out.extend(_captured_consts(sub))
+    return out
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_jitted_steps_take_weights_as_arguments(lm, paged):
+    """Weights reach the jitted decode step as a traced argument: arrays
+    a jitted function closes over become constants of every compiled
+    program, which at published widths copies gigabytes of weights into
+    each executable."""
+    from repro.serve import PagedServeRuntime
+
+    cfg, params = lm
+    kw = dict(page_size=4, num_pages=17) if paged else {}
+    rt = (PagedServeRuntime if paged else ServeRuntime)(
+        cfg, params, max_slots=2, max_len=16, **kw)
+    rt.submit(np.arange(5, dtype=np.int32), max_new_tokens=3, uid=0)
+    rt.run()
+    extra = (jnp.asarray(rt._ptab),) if paged else ()
+    closed = jax.make_jaxpr(rt._decode_fn)(rt._state, rt.params, rt.pack,
+                                           *extra)
+    smallest = min(a.size for a in jax.tree.leaves(params))
+    consts = _captured_consts(closed)
+    assert all(np.size(c) < smallest for c in consts), \
+        [np.shape(c) for c in consts]
+
+
 def test_cache_slot_insert_and_evict(lm):
     cfg, params = lm
     max_slots, max_len = 3, 24

@@ -25,6 +25,7 @@ from repro.sweep import (
     FunctionEvaluator,
     SweepSpec,
     compile_groups,
+    device_tag,
     point_key,
     run_sweep,
     serial_accuracy,
@@ -182,7 +183,8 @@ def test_compile_contract_canary(vehicle):
     sweep = _alpha_sweep()
     pts = sweep.expand()
     groups = compile_groups(
-        [(point_key(ev.signature(), p, sweep.point_protocol()), p)
+        [(point_key(ev.signature(), p, sweep.point_protocol(),
+                    device_tag()), p)
          for p in pts], ev)
     # the original PR 3 pin, verbatim
     assert len(groups) == 1
@@ -390,6 +392,24 @@ def test_cache_misses_on_spec_change(vehicle, tmp_path):
     res = run_sweep(changed, ev, cache_dir=str(tmp_path))
     assert ev.calls == 2, "changed base spec must miss the cache"
     assert res.n_cached == 0
+
+
+def test_cache_misses_on_device_change(vehicle, tmp_path, monkeypatch):
+    """A result computed on one kind of device is never read back on
+    another: the device is part of every point's cache key."""
+    import repro.sweep.executor as executor
+
+    ev = _CountingEvaluator(_evaluator(vehicle))
+    run_sweep(_cache_sweep(), ev, cache_dir=str(tmp_path))
+    assert ev.calls == 1
+    assert device_tag() == "cpu/cpu"
+    monkeypatch.setattr(executor, "device_tag", lambda: "tpu/TPU v5 lite")
+    res = run_sweep(_cache_sweep(), ev, cache_dir=str(tmp_path))
+    assert ev.calls == 2, "another device must miss the cache"
+    assert res.n_cached == 0
+    monkeypatch.undo()
+    res = run_sweep(_cache_sweep(), ev, cache_dir=str(tmp_path))
+    assert ev.calls == 2 and res.n_cached == 2   # both devices kept
 
 
 def test_cache_misses_on_trial_protocol_change(vehicle, tmp_path):
