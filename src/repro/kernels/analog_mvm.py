@@ -97,7 +97,7 @@ def _bitserial_kernel(x_ref, gp_ref, gm_ref, lo_ref, hi_ref, o_ref, *,
 
 
 def _common_call(kernel, x_parts, g_pos, g_neg, adc_lo, adc_hi, *,
-                 bm: int, bn: int, interpret: bool):
+                 name: str, bm: int, bn: int, interpret: bool):
     p, m, rows = x_parts.shape
     _, _, n = g_pos.shape
     if m % bm or n % bn:
@@ -109,6 +109,8 @@ def _common_call(kernel, x_parts, g_pos, g_neg, adc_lo, adc_hi, *,
     return pl.pallas_call(
         kernel,
         grid=grid,
+        name=name,
+        metadata={"kernel": name},
         in_specs=[
             pl.BlockSpec((None, bm, rows), lambda i, j, p_: (p_, i, 0)),
             pl.BlockSpec((None, rows, bn), lambda i, j, p_: (p_, 0, j)),
@@ -139,7 +141,8 @@ def analog_mvm_diff_pallas(
 ) -> jax.Array:
     kern = functools.partial(_diff_kernel, adc_bits=adc_bits, gain=gain)
     return _common_call(kern, x_parts, g_pos, g_neg, adc_lo, adc_hi,
-                        bm=bm, bn=bn, interpret=interpret)
+                        name="analog_mvm_diff", bm=bm, bn=bn,
+                        interpret=interpret)
 
 
 def analog_mvm_bitserial_pallas(
@@ -160,4 +163,5 @@ def analog_mvm_bitserial_pallas(
         _bitserial_kernel, n_bits=n_bits, adc_bits=adc_bits, gain=gain
     )
     return _common_call(kern, x_parts, g_pos, g_neg, adc_lo, adc_hi,
-                        bm=bm, bn=bn, interpret=interpret)
+                        name="analog_mvm_bitserial", bm=bm, bn=bn,
+                        interpret=interpret)

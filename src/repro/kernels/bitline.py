@@ -142,6 +142,8 @@ def bitline_mvm_pallas(
     return pl.pallas_call(
         kern,
         grid=(m // bm, n // bn),
+        name="bitline_mvm",
+        metadata={"kernel": "bitline_mvm"},
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
             pl.BlockSpec((k, bn), lambda i, j: (0, j)),
@@ -214,6 +216,8 @@ def analog_bitline_diff_pallas(
     return pl.pallas_call(
         kern,
         grid=(m // bm, n // bn, p),
+        name="analog_bitline_diff",
+        metadata={"kernel": "analog_bitline_diff"},
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j, p_: (0, 0)),
             pl.BlockSpec((1, 1), lambda i, j, p_: (0, 0)),

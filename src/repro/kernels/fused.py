@@ -248,14 +248,19 @@ def _fused_parasitic_kernel(scale_ref, r_ref, lo_ref, hi_ref, xt_ref,
 
 
 def _mvm_call(kern, x_in, x_spec, g_pos, g_neg, extra, adc_lo, adc_hi,
-              scale, *, m: int, bm: int, bn: int, interpret: bool):
+              scale, *, site: tuple, m: int, bm: int, bn: int,
+              interpret: bool):
     """Shared pallas_call plumbing for both fused MVM kernels.
 
     ``x_in`` is the activation operand in the layout its kernel reads,
     ``x_spec`` its block spec and ``m`` its (padded) row count.
     ``extra`` is a list of additional leading (1, 1) scalar operands (the
-    parasitic ``r_hat``); ``adc_lo/adc_hi`` are per-slice (S,).
+    parasitic ``r_hat``); ``adc_lo/adc_hi`` are per-slice (S,).  ``site``
+    is the call's site class (a :data:`BUILD_SIGNATURES` entry), recorded
+    here and carried in the kernel's metadata beside ``"kernel":
+    "fused_mvm"``, so a profiler trace names the op and its site class.
     """
+    BUILD_SIGNATURES.add(site)
     n_slices, p, rows, n = g_pos.shape
     if m % bm or n % bn:
         raise ValueError(
@@ -274,6 +279,8 @@ def _mvm_call(kern, x_in, x_spec, g_pos, g_neg, extra, adc_lo, adc_hi,
     return pl.pallas_call(
         kern,
         grid=grid,
+        name="fused_mvm",
+        metadata={"kernel": "fused_mvm", "site": str(site)},
         in_specs=scalar_specs + slice_specs + [x_spec, g_spec, g_spec],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, p_: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
@@ -300,14 +307,14 @@ def fused_mvm_pallas(
     interpret: bool,
 ) -> jax.Array:
     """Fused differential analog MVM; returns the dequantized (M, N)."""
-    BUILD_SIGNATURES.add(("linear", g_pos.shape[0], cell_bits, adc_bits,
-                          n_bits, None))
     kern = functools.partial(_fused_diff_kernel, adc_bits=adc_bits,
                              cell_bits=cell_bits, n_bits=n_bits)
     _, m, rows = x_parts.shape
     x_spec = pl.BlockSpec((None, bm, rows), lambda i, j, p_: (p_, i, 0))
+    site = ("linear", g_pos.shape[0], cell_bits, adc_bits, n_bits, None)
     return _mvm_call(kern, x_parts, x_spec, g_pos, g_neg, [], adc_lo,
-                     adc_hi, scale, m=m, bm=bm, bn=bn, interpret=interpret)
+                     adc_hi, scale, site=site, m=m, bm=bm, bn=bn,
+                     interpret=interpret)
 
 
 def fused_mvm_parasitic_pallas(
@@ -327,14 +334,14 @@ def fused_mvm_parasitic_pallas(
     interpret: bool,
 ) -> jax.Array:
     """Fused parasitic differential MVM; returns the dequantized (M, N)."""
-    BUILD_SIGNATURES.add(("parasitic", g_pos.shape[0], cell_bits, adc_bits,
-                          None, n_bits))
     _, rows, m = xt_parts.shape
     kern = functools.partial(_fused_parasitic_kernel, adc_bits=adc_bits,
                              cell_bits=cell_bits, n_bits=n_bits, rows=rows)
     x_spec = pl.BlockSpec((None, rows, bm), lambda i, j, p_: (p_, 0, i))
+    site = ("parasitic", g_pos.shape[0], cell_bits, adc_bits, None, n_bits)
     return _mvm_call(kern, xt_parts, x_spec, g_pos, g_neg, [r_hat], adc_lo,
-                     adc_hi, scale, m=m, bm=bm, bn=bn, interpret=interpret)
+                     adc_hi, scale, site=site, m=m, bm=bm, bn=bn,
+                     interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -439,5 +446,7 @@ def flash_attention_pallas(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
+        name="flash_decode",
+        metadata={"kernel": "flash_decode"},
         interpret=interpret,
     )(kv_len, q, k, v)

@@ -154,5 +154,7 @@ def paged_attention_pallas(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
+        name="paged_attention",
+        metadata={"kernel": "paged_attention"},
         interpret=interpret,
     )(ptab, kv_len, q, k_pages, v_pages)

@@ -19,15 +19,11 @@ replay), and slow nodes.  This module provides the single-process pieces:
   k*std`` outliers, and calls an eviction hook.  On multi-pod deployments
   the hook would demote the slow host and trigger an elastic restart; here
   it records the event (tested with injected delays).
-* ``Heartbeat`` — a daemon-thread liveness file (mtime = last heartbeat),
-  the signal an external supervisor (k8s / SLURM) watches.
 """
 
 from __future__ import annotations
 
-import os
 import statistics
-import threading
 import time
 from typing import Callable, List, Optional, Tuple
 
@@ -135,31 +131,3 @@ class StragglerMonitor:
         self.record(time.perf_counter() - t0)
         return out
 
-
-class Heartbeat:
-    def __init__(self, path: str, interval_s: float = 5.0):
-        self.path = path
-        self.interval = interval_s
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-
-    def start(self):
-        def beat():
-            while not self._stop.wait(self.interval):
-                self._touch()
-
-        self._touch()
-        self._thread = threading.Thread(target=beat, daemon=True)
-        self._thread.start()
-
-    def _touch(self):
-        with open(self.path, "w") as f:
-            f.write(str(time.time()))
-
-    def stop(self):
-        self._stop.set()
-        if self._thread:
-            self._thread.join()
-
-    def age(self) -> float:
-        return time.time() - os.path.getmtime(self.path)

@@ -48,7 +48,6 @@ programming/sampling key derivations are untouched.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -70,7 +69,6 @@ from repro.serve.runtime import (
     ServeRuntime,
     SlotState,
     _Pending,
-    _pow2_at_least,
     request_key,
     sample_tokens,
 )
@@ -234,7 +232,7 @@ class PagedServeRuntime(ServeRuntime):
     def _prefill_group(self, key: Tuple,
                        items: List[Tuple[_Pending, int]]) -> None:
         ctx, bucket = key
-        g = min(_pow2_at_least(len(items)), self.max_slots)
+        g = self._group_size(len(items))
         ncp = ctx // self.page_size
         suffix = np.zeros((g, bucket), np.int32)
         true_lens = np.ones((g,), np.int32)
@@ -271,14 +269,7 @@ class PagedServeRuntime(ServeRuntime):
                          jnp.asarray(true_lens), jnp.asarray(slots),
                          jnp.asarray(max_new), jnp.stack(keys),
                          jnp.asarray(ctx_pages), jnp.asarray(ptabg))
-        self._stats["prefill_calls"] += 1
-        if self.measure_ttft:
-            jax.block_until_ready(self._state.tok)
-        now = time.perf_counter()
-        for req, _ in items:
-            req.ttft_s = now - req.submit_t
-            req.done_step = self._stats["decode_steps"] + req.max_new - 1
-            self._stats["ttft_s"].append(req.ttft_s)
+        self._dispatched(items)
 
     def _make_paged_prefill_fn(self):
         cfg = self.cfg

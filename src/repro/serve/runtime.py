@@ -42,6 +42,14 @@ The scheduler loop (one :meth:`ServeRuntime.step`):
 3. **collect** — completed requests are returned to the caller and their
    slots freed for the next admission.
 
+Each phase runs inside a ``jax.profiler.TraceAnnotation`` span on the
+profiler's own clock (``serve.step`` around ``serve.maintain``,
+``serve.admit`` holding one ``serve.prefill`` per group,
+``serve.decode`` and ``serve.collect``; ``serve.submit`` and
+``serve.read`` outside the step), so a device trace shows which phase
+the device waited on; a request's spans carry its uid.  The same phases
+feed always-on host-time counters in :attr:`ServeRuntime.stats`.
+
 ``gang=True`` degrades the scheduler to static batching (admit only when
 every slot is free, pad the whole batch to one bucket) — the baseline
 ``benchmarks/servebench.py`` measures continuous batching against.
@@ -49,6 +57,7 @@ every slot is free, pad the whole batch to one bucket) — the baseline
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -171,6 +180,11 @@ class Completion:
     tokens: np.ndarray          # (n_generated,) int32
     prompt_len: int
     ttft_s: float               # submit -> first token wall time
+
+
+#: the scheduler's phases, each timed into ``stats`` and traced as a
+#: ``serve.<phase>`` span
+PHASES = ("maintain", "admit", "prefill", "decode", "collect")
 
 
 def _pow2_at_least(n: int) -> int:
@@ -343,7 +357,11 @@ class ServeRuntime:
         self._stats = {"decode_steps": 0, "prefill_calls": 0,
                        "occupancy_sum": 0, "tokens_out": 0, "ttft_s": [],
                        "heal_events": 0, "bands_reprogrammed": 0,
-                       "recalibrations": 0, "probe_losses": []}
+                       "recalibrations": 0, "probe_losses": [],
+                       "device_syncs": 0}
+        for phase in PHASES:
+            self._stats[phase + "_s"] = 0.0
+            self._stats[phase + "_max_s"] = 0.0
 
     def _init_layers(self):
         """The slot-batched cache tree this runtime decodes over.  Hook
@@ -354,10 +372,31 @@ class ServeRuntime:
 
     @property
     def stats(self) -> Dict[str, Any]:
-        """Scheduling telemetry since the last :meth:`reset`:
-        ``decode_steps``, ``prefill_calls``, mean ``occupancy`` (busy
-        slots per decode step / ``max_slots``), ``tokens_out``, and the
-        per-request ``ttft_s`` list."""
+        """An operator's counters since the last :meth:`reset`, always on:
+
+        * ``decode_steps``, ``prefill_calls``: decode steps and prefill
+          groups dispatched;
+        * ``occupancy``: mean busy slots per decode step / ``max_slots``;
+        * ``tokens_out``: tokens of the requests collected;
+        * ``ttft_s``: per request, submit to admission (to its first
+          token with ``measure_ttft``), in seconds;
+        * ``heal_events``, ``bands_reprogrammed``, ``recalibrations``,
+          ``probe_losses``: the health loop's (with a ``manager``);
+        * ``<phase>_s`` and ``<phase>_max_s`` for each of :data:`PHASES`:
+          host seconds spent in the phase in all and in its longest call
+          (``time.perf_counter``).  ``maintain``, ``admit``, ``decode``
+          and ``collect`` are disjoint parts of :meth:`step`;
+          ``prefill`` (one group's dispatch) lies inside ``admit``, as
+          does a collect made during admission.  ``prefill`` and
+          ``decode`` are dispatches of jitted programs; a dispatch
+          returns before the device runs the program, but on a TPU not
+          before the program dispatched ahead of it (it reads the slot
+          state that one writes) has run;
+        * ``device_syncs``: blocking reads of device state — each collect
+          that read the slot state (a step on which a lane may finish;
+          every step with EOS stopping on), each ``measure_ttft`` wait
+          and each :meth:`stream`.
+        """
         s = dict(self._stats)
         s["ttft_s"] = list(s["ttft_s"])      # snapshot, not the live list
         s["probe_losses"] = list(s["probe_losses"])
@@ -369,6 +408,12 @@ class ServeRuntime:
 
     def submit(self, prompt, *, max_new_tokens: int, uid=None):
         """Queue one request; returns its uid (auto-assigned if None)."""
+        with jax.profiler.TraceAnnotation("serve.submit") as span:
+            uid = self._enqueue(prompt, max_new_tokens, uid)
+            span.set_metadata(uid=str(uid))
+        return uid
+
+    def _enqueue(self, prompt, max_new_tokens: int, uid):
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -419,21 +464,55 @@ class ServeRuntime:
 
     def step(self) -> List[Completion]:
         """One scheduler iteration: maintain -> admit -> decode -> collect."""
-        self._maintain()
-        self._admit()
-        early, self._early = self._early, []
-        # lanes past their budget (done_step <= t: retired at prefill, or
-        # certainly finished) need collecting, not decoding — don't burn a
-        # model step on them.  An EOS that fired early on a lane with
-        # budget left is device-side knowledge; _collect (which syncs
-        # every step when EOS is on) frees that slot one step later.
         t = self._stats["decode_steps"]
-        live = sum(p is not None and p.done_step > t for p in self._slots)
-        if live:
-            self._run_decode()
-            self._stats["decode_steps"] += 1
-            self._stats["occupancy_sum"] += live
-        return early + self._collect()
+        with jax.profiler.TraceAnnotation("serve.step", step=t) as span:
+            with self._phase("maintain"):
+                self._maintain()
+            with self._phase("admit"):
+                self._admit()
+            early, self._early = self._early, []
+            # lanes past their budget (done_step <= t: retired at prefill,
+            # or certainly finished) need collecting, not decoding — don't
+            # burn a model step on them.  An EOS that fired early on a lane
+            # with budget left is device-side knowledge; _collect (which
+            # syncs every step when EOS is on) frees that slot one step
+            # later.
+            live = sum(p is not None and p.done_step > t
+                       for p in self._slots)
+            span.set_metadata(live=live)
+            if live:
+                with self._phase("decode"):
+                    self._run_decode()
+                self._stats["decode_steps"] += 1
+                self._stats["occupancy_sum"] += live
+            return early + self._collect()
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, counted: bool = True, **args):
+        """Span ``serve.<name>`` (one of :data:`PHASES`) with ``args``
+        and, when ``counted``, the phase's host-time counters in
+        :attr:`stats`.  With no trace running a span costs about a
+        microsecond."""
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve." + name, **args) as span:
+            yield span
+        if counted:
+            dt = time.perf_counter() - t
+            self._stats[name + "_s"] += dt
+            self._stats[name + "_max_s"] = max(self._stats[name + "_max_s"],
+                                               dt)
+
+    def stream(self) -> Tuple[np.ndarray, np.ndarray]:
+        """``(tok, emitted)``: each slot's newest token and how many it has
+        emitted, as two ``(max_slots,)`` arrays read off the device in one
+        blocking read (span ``serve.read``, counted in ``device_syncs``) —
+        what a streaming server sends after each :meth:`step`.  A free
+        slot's entries are stale."""
+        with jax.profiler.TraceAnnotation("serve.read"):
+            self._stats["device_syncs"] += 1
+            tok, emitted = jax.device_get((self._state.tok,
+                                           self._state.emitted))
+        return tok, emitted
 
     def _run_decode(self) -> None:
         """Dispatch one jitted decode step over the slot state.  Hook for
@@ -515,7 +594,7 @@ class ServeRuntime:
             # (syncing) collect whenever EOS is enabled
             if not (may_retire or self._eos_enabled):
                 return
-            done = self._collect()
+            done = self._collect(counted=False)
             if not done:
                 return
             self._early.extend(done)
@@ -547,7 +626,11 @@ class ServeRuntime:
         # sort by cached-prefix length, so a prefix donor's prefill is
         # dispatched before any same-batch borrower gathers its pages)
         for key in sorted(groups):
-            self._prefill_group(key, groups[key])
+            items = groups[key]
+            with self._phase("prefill", bucket=key[-1],
+                             group=self._group_size(len(items)),
+                             uids=" ".join(str(r.uid) for r, _ in items)):
+                self._prefill_group(key, items)
         return True
 
     def _reserve(self, req: _Pending) -> bool:
@@ -567,10 +650,14 @@ class ServeRuntime:
                 return b
         raise AssertionError(n)         # unreachable: submit() validates
 
+    def _group_size(self, n: int) -> int:
+        """Rows of the prefill program that admits ``n`` requests."""
+        return min(_pow2_at_least(n), self.max_slots)
+
     def _prefill_group(self, key: Tuple,
                        items: List[Tuple[_Pending, int]]) -> None:
         bucket = key[-1]
-        g = min(_pow2_at_least(len(items)), self.max_slots)
+        g = self._group_size(len(items))
         prompts = np.zeros((g, bucket), np.int32)
         true_lens = np.ones((g,), np.int32)
         slots = np.full((g,), self.max_slots, np.int32)   # dummy -> dropped
@@ -591,10 +678,16 @@ class ServeRuntime:
                          jnp.asarray(prompts),
                          jnp.asarray(true_lens), jnp.asarray(slots),
                          jnp.asarray(max_new), jnp.stack(keys))
+        self._dispatched(items)
+
+    def _dispatched(self, items: List[Tuple[_Pending, int]]) -> None:
+        """Bookkeeping once a group's prefill is dispatched: count it and
+        stamp each request's ``ttft_s`` and retiring step."""
         self._stats["prefill_calls"] += 1
         if self.measure_ttft:
             # first tokens exist only once the async dispatch lands —
             # without the block, ttft_s is submit->admission latency
+            self._stats["device_syncs"] += 1
             jax.block_until_ready(self._state.tok)
         now = time.perf_counter()
         for req, _ in items:
@@ -602,7 +695,19 @@ class ServeRuntime:
             req.done_step = self._stats["decode_steps"] + req.max_new - 1
             self._stats["ttft_s"].append(req.ttft_s)
 
-    def _collect(self) -> List[Completion]:
+    def _collect(self, counted: bool = True) -> List[Completion]:
+        """Retire the lanes that finished (span ``serve.collect``, args
+        ``synced`` and the ``finished`` uids); ``counted`` False inside
+        admission, whose time it is."""
+        with self._phase("collect", counted) as span:
+            syncs = self._stats["device_syncs"]
+            done = self._retire()
+            span.set_metadata(
+                synced=self._stats["device_syncs"] - syncs,
+                finished=" ".join(str(c.uid) for c in done))
+        return done
+
+    def _retire(self) -> List[Completion]:
         busy = [p for p in self._slots if p is not None]
         if not busy:
             return []
@@ -613,6 +718,7 @@ class ServeRuntime:
             t = self._stats["decode_steps"]
             if all(p.done_step > t for p in busy):
                 return []
+        self._stats["device_syncs"] += 1
         active = np.asarray(self._state.active)
         finished = [i for i, p in enumerate(self._slots)
                     if p is not None and not active[i]]

@@ -8,6 +8,10 @@ at the widths qwen1.5-4b serves: d_model 2560, d_ff 6912, 20 heads of
 128, decode batch 8, and Design A's ``max_rows=1152`` partitioning
 (K = 2560 -> 3 partitions of 854 rows, K = 6912 -> 6 of 1152).
 
+Each compiled kernel also carries its tag: the Pallas call's ``name=``
+names the instruction and its ``metadata=`` appears in the op's own text
+as ``kernel_metadata``, which is what a profiler trace's op names hold.
+
 Nothing runs: a compile that passes says nothing about results or
 times.  The topology is described inside a module-scoped fixture, never
 at import, so that only the test worker given this file loads the TPU
@@ -15,6 +19,7 @@ compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +54,19 @@ def _compile_text(fn, shapes, sharding) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _assert_tagged(text: str, kernel: str, site=None) -> None:
+    """Every Mosaic call in ``text`` is ``kernel``'s, named after it and
+    tagged with it (and with its fused site class)."""
+    calls = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+                       r"[^\n]*kernel_metadata=\{\n(.*?)\n\}\}", text, re.S)
+    assert calls, "no tagged tpu_custom_call in the compiled text"
+    for name, meta in calls:
+        assert name.startswith(kernel + "."), name
+        assert f'"kernel":"{kernel}"' in meta, meta
+        if site is not None:
+            assert f'"site":"{site}"' in meta, meta
+
+
 def _fused_shapes(k, n, n_slices, m=BATCH):
     spec = design_a()
     p, rows = spec.n_partitions(k), spec.rows_per_partition(k)
@@ -71,7 +89,9 @@ def test_fused_mvm_compiles_at_published_widths(one_chip, k, n):
                              cell_bits=7, n_bits=None, scale=scale,
                              interpret=False)
 
-    assert "tpu_custom_call" in _compile_text(f, shapes, one_chip)
+    text = _compile_text(f, shapes, one_chip)
+    assert "tpu_custom_call" in text
+    _assert_tagged(text, "fused_mvm", "('linear', 1, 7, 8, None, None)")
 
 
 @pytest.mark.parametrize("m", [BATCH, 128], ids=["decode", "prefill"])
@@ -88,7 +108,10 @@ def test_fused_mvm_eight_slices_fit_vmem(one_chip, n_bits, m):
                              cell_bits=1, n_bits=n_bits, scale=scale,
                              interpret=False)
 
-    assert "tpu_custom_call" in _compile_text(f, shapes, one_chip)
+    text = _compile_text(f, shapes, one_chip)
+    assert "tpu_custom_call" in text
+    _assert_tagged(text, "fused_mvm",
+                   f"('linear', 8, 1, 8, {n_bits}, None)")
 
 
 @pytest.mark.parametrize("n_slices", [1, 2])
@@ -101,7 +124,11 @@ def test_fused_mvm_parasitic_compiles(one_chip, n_slices):
             adc_bits=8, cell_bits=7 if n_slices == 1 else 4, n_bits=7,
             scale=scale, interpret=False)
 
-    assert "tpu_custom_call" in _compile_text(f, shapes, one_chip)
+    text = _compile_text(f, shapes, one_chip)
+    assert "tpu_custom_call" in text
+    cell_bits = 7 if n_slices == 1 else 4
+    _assert_tagged(text, "fused_mvm",
+                   f"('parasitic', {n_slices}, {cell_bits}, 8, None, 7)")
 
 
 def test_bitline_mvm_compiles(one_chip):
@@ -111,7 +138,9 @@ def test_bitline_mvm_compiles(one_chip):
         return ops.bitline_mvm(g, x, jnp.float32(1e-4), interpret=False)
 
     shapes = [((rows, 256), jnp.float32), ((BATCH, rows), jnp.float32)]
-    assert "tpu_custom_call" in _compile_text(f, shapes, one_chip)
+    text = _compile_text(f, shapes, one_chip)
+    assert "tpu_custom_call" in text
+    _assert_tagged(text, "bitline_mvm")
 
 
 @pytest.mark.parametrize("parasitic", [False, True])
@@ -129,7 +158,10 @@ def test_legacy_analog_mvm_compiles(one_chip, parasitic):
 
     shapes = [((BATCH, p, rows), jnp.float32),
               ((p, rows, 256), jnp.float32), ((p, rows, 256), jnp.float32)]
-    assert "tpu_custom_call" in _compile_text(f, shapes, one_chip)
+    text = _compile_text(f, shapes, one_chip)
+    assert "tpu_custom_call" in text
+    _assert_tagged(text, "analog_bitline_diff" if parasitic
+                   else "analog_mvm_diff")
 
 
 def test_flash_attention_decode_compiles(one_chip):
@@ -142,7 +174,9 @@ def test_flash_attention_decode_compiles(one_chip):
               ((BATCH, cache, HEADS, HEAD_DIM), jnp.bfloat16),
               ((BATCH, cache, HEADS, HEAD_DIM), jnp.bfloat16),
               ((BATCH,), jnp.int32)]
-    assert "tpu_custom_call" in _compile_text(f, shapes, one_chip)
+    text = _compile_text(f, shapes, one_chip)
+    assert "tpu_custom_call" in text
+    _assert_tagged(text, "flash_decode")
 
 
 def test_paged_attention_compiles(one_chip):
@@ -156,4 +190,6 @@ def test_paged_attention_compiles(one_chip):
               ((pool, page, HEADS, HEAD_DIM), jnp.bfloat16),
               ((pool, page, HEADS, HEAD_DIM), jnp.bfloat16),
               ((BATCH, per_row), jnp.int32), ((BATCH,), jnp.int32)]
-    assert "tpu_custom_call" in _compile_text(f, shapes, one_chip)
+    text = _compile_text(f, shapes, one_chip)
+    assert "tpu_custom_call" in text
+    _assert_tagged(text, "paged_attention")
