@@ -362,14 +362,16 @@ def flash_decode_bench(timer: Timer):
     serving runtime's fused-vs-oracle agreement contract rests on it."""
     import numpy as np
 
-    # (B rows, cache S, KV heads, GQA group, head dim)
-    shapes = [(4, 64, 4, 2, 64), (8, 96, 2, 4, 64), (3, 40, 8, 1, 32)]
+    # (B rows, cache S, KV heads, GQA group, head dim); the cache in
+    # bfloat16 as served, each chunk chosen by the kernel's shape rule
+    shapes = [(4, 64, 4, 2, 64), (8, 96, 2, 4, 64), (3, 40, 8, 1, 32),
+              (4, 64, 5, 1, 32)]
     for (b, s, kv, g, hd) in shapes:
         h = kv * g
         ks = jax.random.split(jax.random.PRNGKey(b + s), 3)
         q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)
-        ck = jax.random.normal(ks[1], (b, s, kv, hd), jnp.float32)
-        cv = jax.random.normal(ks[2], (b, s, kv, hd), jnp.float32)
+        ck = jax.random.normal(ks[1], (b, s, kv, hd), jnp.bfloat16)
+        cv = jax.random.normal(ks[2], (b, s, kv, hd), jnp.bfloat16)
         import numpy.random as npr
         fills = jnp.asarray(npr.default_rng(b).integers(1, s + 1, size=b),
                             jnp.int32)

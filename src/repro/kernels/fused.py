@@ -76,11 +76,26 @@ operand — the sweep engine batches traced ``on_off_ratio`` (hence traced
 ``gain``) points through a single compilation, so the kernel must not
 bake it in (same rule as ``r_hat`` in ``kernels.bitline``).
 
-``flash_attention_pallas`` is the dense-cache sibling of PR 8's paged
+``flash_attention_pallas`` is the dense-cache sibling of the paged
 kernel: same three-phase (max / materialize / pure-add) structure and
 bitwise discipline, but blocks are addressed arithmetically as
-``(row, j)`` chunks of the per-slot ``(B, S, KV, hd)`` cache — no block
-table, one scalar-prefetch operand for the per-row fills.
+``(row, j)`` chunks of the per-slot cache — no block table, one
+scalar-prefetch operand for the per-row fills.  It touches the cache as
+little as the arithmetic allows:
+
+* chunks are large, ``ops.flash_decode_block`` positions chosen from the
+  cache's shape and dtype (the largest multiple of 8 dividing the cache
+  under a VMEM budget), so the grid has few steps;
+* every K/V index map stops at the row's last filled chunk and all work
+  is gated on it, so a chunk wholly past the fill is neither fetched nor
+  computed (bitwise-neutral: the oracle adds exact zeros for it);
+* phase 0 keeps each chunk's scores in VMEM scratch, so K is read once,
+  V only in phase 1, and phase 2 reads nothing;
+* K and V arrive in their stored dtype and are upcast per chunk in VMEM
+  (exact), in the layout the chip stores the cache in: ``(B, S, KV, hd)``
+  or, for a KV count the TPU lays out head-major, ``(B, KV, S, hd)``
+  (``ops.flash_decode_heads_major``), so no copy of the cache is made
+  before the call.
 """
 
 from __future__ import annotations
@@ -103,7 +118,9 @@ from repro.kernels.paged import NEG_INF
 #: (bm, rows) input bit planes and per-slice temporaries of the unrolled
 #: loops.  Mosaic refuses that kernel at 32 MiB and accepts it at 40 MiB
 #: (``tests/test_tpu_compile.py``); 48 MiB leaves a fifth to spare and is
-#: under half of a TPU v5e core's 128 MiB of VMEM.
+#: under half of a TPU v5e core's 128 MiB of VMEM.  Flash-decode takes the
+#: same limit: reading a ``(B, S, KV, hd)`` cache it relays each chunk out
+#: per KV head in VMEM, 26.5 MB at 384 positions of 8 heads of 128.
 VMEM_LIMIT_BYTES = 48 * 2 ** 20
 
 
@@ -350,16 +367,23 @@ def fused_mvm_parasitic_pallas(
 
 
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, da_ref, *,
-                  block: int, scale: float):
+                  m_ref, l_ref, acc_ref, sc_ref, da_ref, *,
+                  block: int, scale: float, heads_major: bool):
     """Three-phase flash-decode over dense cache chunks — the body of
     ``kernels.paged._paged_kernel`` with arithmetic block addressing in
     place of the block-table gather (see that module for why the phases
-    and the per-chunk term slots are what make it bit-exact)."""
+    and the per-chunk term slots are what make it bit-exact).
+
+    Phase 0 computes each chunk's masked scores once and keeps them in
+    ``sc_ref``; phase 1 reads them back (the same values a recomputation
+    would give) and reads V; phase 2 only adds.  Chunks past the row's
+    last filled one do nothing: in the oracle they contribute
+    ``max(m, NEG_INF) = m``, ``l + 0`` and ``acc + 0``."""
     b = pl.program_id(0)
     phase = pl.program_id(1)
     j = pl.program_id(2)
     n_blocks = pl.num_programs(2)
+    filled = j <= _last_block(len_ref[b], block, n_blocks)
 
     @pl.when((phase == 0) & (j == 0))
     def _init():
@@ -368,29 +392,30 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     kv_heads, g, hd = acc_ref.shape
-    qg = q_ref[0].reshape(kv_heads, g, hd) * scale       # (KV, g, hd) f32
-    k = k_ref[0]                                         # (block, KV, hd)
+    pkd = "kpd" if heads_major else "pkd"                # the chunk's layout
 
-    s = jnp.einsum("kgd,pkd->kgp", qg, k,
-                   preferred_element_type=jnp.float32,
-                   precision=jax.lax.Precision.HIGHEST)  # (KV, g, block)
-    k_pos = j * block + jax.lax.iota(jnp.int32, block)
-    valid = k_pos < len_ref[b]
-    s = jnp.where(valid[None, None, :], s, NEG_INF)
-
-    @pl.when(phase == 0)
-    def _max_pass():
+    @pl.when((phase == 0) & filled)
+    def _scores():
+        qg = q_ref[0].reshape(kv_heads, g, hd) * scale   # (KV, g, hd) f32
+        s = jnp.einsum(f"kgd,{pkd}->kgp", qg, k_ref[0].astype(jnp.float32),
+                       preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)  # (KV, g, block)
+        k_pos = j * block + jax.lax.iota(jnp.int32, block)
+        valid = k_pos < len_ref[b]
+        s = jnp.where(valid[None, None, :], s, NEG_INF)
+        sc_ref[j] = s
         m_ref[...] = jnp.maximum(m_ref[...], jnp.max(s, axis=-1))
 
-    @pl.when(phase == 1)
+    @pl.when((phase == 1) & filled)
     def _materialize():
-        p = jnp.exp(s - m_ref[...][..., None])           # (KV, g, block)
+        p = jnp.exp(sc_ref[j] - m_ref[...][..., None])   # (KV, g, block)
         l_ref[...] = l_ref[...] + jnp.sum(p, axis=-1)
-        da_ref[j] = jnp.einsum("kgp,pkd->kgd", p, v_ref[0],
+        da_ref[j] = jnp.einsum(f"kgp,{pkd}->kgd", p,
+                               v_ref[0].astype(jnp.float32),
                                preferred_element_type=jnp.float32,
                                precision=jax.lax.Precision.HIGHEST)
 
-    @pl.when(phase == 2)
+    @pl.when((phase == 2) & filled)
     def _accumulate():
         acc_ref[...] = acc_ref[...] + da_ref[j]
 
@@ -400,18 +425,27 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = out.reshape(kv_heads * g, hd)
 
 
+def _last_block(fill, block: int, n_blocks):
+    """Index of the chunk that holds a row's last filled position (a row
+    holds at least one)."""
+    return jnp.minimum((jnp.maximum(fill, 1) - 1) // block, n_blocks - 1)
+
+
 def flash_attention_pallas(
     q: jax.Array,          # (B, H, hd) float32
-    k: jax.Array,          # (B, S, KV, hd) float32 dense per-slot cache
-    v: jax.Array,          # (B, S, KV, hd) float32
-    kv_len: jax.Array,     # (B,) int32 valid positions per row
+    k: jax.Array,          # (B, S, KV, hd) dense per-slot cache, as stored
+    v: jax.Array,          # (B, S, KV, hd); both (B, KV, S, hd) if heads_major
+    kv_len: jax.Array,     # (B,) int32 valid positions per row, >= 1
     *,
     block: int,
     scale: float,
+    heads_major: bool,
     interpret: bool,
 ) -> jax.Array:
     b, h, hd = q.shape
-    _, seq, kv_heads, _ = k.shape
+    seq, kv_heads = k.shape[1:3]
+    if heads_major:
+        seq, kv_heads = kv_heads, seq
     if seq % block:
         raise ValueError(f"cache length {seq} not divisible by "
                          f"block {block}")
@@ -420,17 +454,36 @@ def flash_attention_pallas(
                          "KV heads")
     g = h // kv_heads
     n_blocks = seq // block
-    kern = functools.partial(_flash_kernel, block=block, scale=scale)
+    kv_block = (1, kv_heads, block, hd) if heads_major else \
+        (1, block, kv_heads, hd)
+
+    def chunk(bi, j):
+        return (bi, 0, j, 0) if heads_major else (bi, j, 0, 0)
+
+    # K is read in phase 0 only and V in phase 1 only; elsewhere each
+    # holds the index it had (or is about to have), and every index stops
+    # at the row's last filled chunk, so Pallas starts no DMA for a
+    # chunk twice or for one past the fill
+    def k_index(bi, ph, j, ln):
+        jk = jnp.where(ph == 0, j, n_blocks - 1)
+        return chunk(bi, jnp.minimum(
+            jk, _last_block(ln[bi], block, n_blocks)))
+
+    def v_index(bi, ph, j, ln):
+        jv = jnp.where(ph == 0, 0, jnp.where(ph == 1, j, n_blocks - 1))
+        return chunk(bi, jnp.minimum(
+            jv, _last_block(ln[bi], block, n_blocks)))
+
+    kern = functools.partial(_flash_kernel, block=block, scale=scale,
+                             heads_major=heads_major)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, 3, n_blocks),
         in_specs=[
             pl.BlockSpec((1, h, hd),
                          lambda bi, ph, j, ln: (bi, 0, 0)),
-            pl.BlockSpec((1, block, kv_heads, hd),
-                         lambda bi, ph, j, ln: (bi, j, 0, 0)),
-            pl.BlockSpec((1, block, kv_heads, hd),
-                         lambda bi, ph, j, ln: (bi, j, 0, 0)),
+            pl.BlockSpec(kv_block, k_index),
+            pl.BlockSpec(kv_block, v_index),
         ],
         out_specs=pl.BlockSpec((1, h, hd),
                                lambda bi, ph, j, ln: (bi, 0, 0)),
@@ -438,6 +491,8 @@ def flash_attention_pallas(
             pltpu.VMEM((kv_heads, g), jnp.float32),       # global max
             pltpu.VMEM((kv_heads, g), jnp.float32),       # denominator
             pltpu.VMEM((kv_heads, g, hd), jnp.float32),   # weighted acc
+            pltpu.VMEM((n_blocks, kv_heads, g, block),
+                       jnp.float32),                      # per-chunk scores
             pltpu.VMEM((n_blocks, kv_heads, g, hd),
                        jnp.float32),                      # per-chunk terms
         ],
@@ -448,5 +503,7 @@ def flash_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
         name="flash_decode",
         metadata={"kernel": "flash_decode"},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(kv_len, q, k, v)

@@ -276,13 +276,47 @@ def fused_mvm_parasitic(
     return out[:m, :n]
 
 
+#: VMEM the flash-decode kernel may give its double-buffered K and V
+#: blocks (2 arrays x 2 buffers).  Larger blocks cut the kernel's per-grid-
+#: step cost; smaller ones waste fewer positions past a row's fill (half a
+#: block on average).  Both trade in bytes, so the budget is in bytes.  On
+#: a TPU v5e, at 16 rows of 768 x 20 x 128 bfloat16 filled ~270 deep, 128
+#: positions (2.6 MB) ran fastest, 256 (5.2 MB) 1-7% slower, 64 and 384
+#: 11-18% slower, 768 60% slower; 3 MiB takes 128.
+FLASH_DECODE_KV_VMEM_BYTES = 3 * 2 ** 20
+
+
+def flash_decode_heads_major(kv_heads: int) -> bool:
+    """Whether the flash-decode kernel reads the cache head-major, as
+    ``(B, KV, S, hd)``.  A TPU tiles the two minor dims of a ``(..., S,
+    KV, hd)`` cache in 8-row tiles (a smaller tile below 5 heads), and
+    lays a KV count that fills no whole tile head-major, the tiles running
+    along S.  Reading the cache in the view it is laid out in makes the
+    view a bitcast; the other view would copy the whole cache every call
+    (``tests/test_tpu_compile.py`` pins both)."""
+    return kv_heads > 4 and kv_heads % 8 != 0
+
+
+def flash_decode_block(seq: int, kv_heads: int, hd: int, dtype) -> int:
+    """Cache positions per flash-decode chunk for a ``(seq, kv_heads, hd)``
+    cache stored in ``dtype``: the largest multiple of 8 that divides the
+    cache length rounded up to 8 (so a cache of a multiple of 8 is never
+    padded) whose double-buffered K and V blocks fit
+    :data:`FLASH_DECODE_KV_VMEM_BYTES`; 8 where none does."""
+    padded = -(-seq // 8) * 8
+    per_pos = 4 * kv_heads * hd * jnp.dtype(dtype).itemsize
+    fits = [blk for blk in range(8, padded + 1, 8)
+            if padded % blk == 0 and blk * per_pos <= FLASH_DECODE_KV_VMEM_BYTES]
+    return max(fits, default=8)
+
+
 def flash_attention_decode(
     q: jax.Array,          # (B, H, hd)
     k: jax.Array,          # (B, S, KV, hd) dense per-slot cache
     v: jax.Array,          # (B, S, KV, hd)
-    kv_len: jax.Array,     # (B,) int32 valid positions per row
+    kv_len: jax.Array,     # (B,) int32 valid positions per row, >= 1
     *,
-    block: int = 8,
+    block: int | None = None,
     scale: float | None = None,
     backend: str = "kernel",
     interpret: bool | None = None,
@@ -292,6 +326,12 @@ def flash_attention_decode(
     addressed arithmetically as (row, j) blocks — no block table, no
     gather — and per-row fills arrive by scalar prefetch, so positions at
     or beyond ``kv_len[b]`` contribute exact zeros.
+
+    ``block`` (cache positions per chunk) defaults to
+    :func:`flash_decode_block` of the cache's shape and dtype.  The kernel
+    reads K and V in their stored dtype and upcasts each chunk in VMEM
+    (exact), reads each row's chunks only up to its fill, K once (its
+    scores are kept for the second phase) and V once.
 
     ``backend="oracle"`` runs the bitwise-identical jnp mirror (the
     chunked cache viewed as a paged pool with an arange table).  The
@@ -306,29 +346,32 @@ def flash_attention_decode(
     interpret = _use_interpret() if interpret is None else interpret
     b, h, hd = q.shape
     scale = hd ** -0.5 if scale is None else scale
+    if block is None:
+        block = flash_decode_block(*k.shape[1:], k.dtype)
     if not interpret and block % 8:
         raise ValueError(
             f"block={block} must be a multiple of 8 (sublane) for the "
             "compiled TPU kernel")
     kp = _pad_to(k, 1, block)
     vp = _pad_to(v, 1, block)
-    qp = q
+    qp = q.astype(jnp.float32)
     if not interpret:
         qp = _pad_to(qp, 2, 128)
         kp = _pad_to(kp, 3, 128)
         vp = _pad_to(vp, 3, 128)
     kv_len = jnp.asarray(kv_len, jnp.int32)
+    heads_major = flash_decode_heads_major(k.shape[2])
     if backend == "oracle":
         out = _k_ref.flash_attention_decode(
-            qp.astype(jnp.float32), kp.astype(jnp.float32),
-            vp.astype(jnp.float32), kv_len,
-            block=block, scale=float(scale),
+            qp, kp.astype(jnp.float32), vp.astype(jnp.float32), kv_len,
+            block=block, scale=float(scale), heads_major=heads_major,
         )
     else:
+        if heads_major:
+            kp, vp = jnp.swapaxes(kp, 1, 2), jnp.swapaxes(vp, 1, 2)
         out = _k_fused.flash_attention_pallas(
-            qp.astype(jnp.float32), kp.astype(jnp.float32),
-            vp.astype(jnp.float32), kv_len,
-            block=block, scale=float(scale), interpret=interpret,
+            qp, kp, vp, kv_len, block=block, scale=float(scale),
+            heads_major=heads_major, interpret=interpret,
         )
     return out[:, :, :hd].astype(q.dtype)
 

@@ -80,6 +80,7 @@ def paged_attention_decode(
     kv_len: jax.Array,     # (B,) int32 valid positions per row
     *,
     scale=None,
+    heads_major: bool = False,
 ) -> jax.Array:
     """Gather oracle for the paged-attention decode kernel.
 
@@ -99,12 +100,19 @@ def paged_attention_decode(
     applies — because size-1 page einsums degenerate to elementwise
     code whose FMA contraction is fusion-context-dependent, which would
     make "bitwise" ill-defined.
+
+    ``heads_major`` pages are ``(P, KV, page_size, hd)``, the layout the
+    dense flash-decode kernel reads for some KV counts: its contractions
+    then take that operand layout too, since XLA-CPU rounds a one-query
+    (``g == 1``) contraction differently per operand layout.
     """
     neg_inf = -1e30                      # layers.NEG_INF / paged.NEG_INF
     b, h, hd = q.shape
     _, page_size, kv_heads, _ = k_pages.shape
+    if heads_major:
+        page_size, kv_heads = kv_heads, page_size
     n_pages = ptab.shape[1]
-    if page_size == 1 and n_pages > 1:
+    if page_size == 1 and n_pages > 1 and not heads_major:
         tab = jnp.asarray(ptab, jnp.int32)
         return paged_attention_decode(
             q, k_pages[:, 0][tab], v_pages[:, 0][tab],
@@ -114,13 +122,14 @@ def paged_attention_decode(
     kv_len = jnp.asarray(kv_len, jnp.int32)
     kpf = k_pages.astype(jnp.float32)
     vpf = v_pages.astype(jnp.float32)
+    pkd = "kpd" if heads_major else "pkd"
 
     def row_fn(args):
         q_row, tab_row, len_row = args
         qg = q_row.astype(jnp.float32).reshape(kv_heads, g, hd) * scale
 
         def logits(j):
-            s = jnp.einsum("kgd,pkd->kgp", qg, kpf[tab_row[j]],
+            s = jnp.einsum(f"kgd,{pkd}->kgp", qg, kpf[tab_row[j]],
                            preferred_element_type=jnp.float32,
                            precision=jax.lax.Precision.HIGHEST)
             k_pos = j * page_size + jnp.arange(page_size)
@@ -136,7 +145,7 @@ def paged_attention_decode(
         def contrib(j):
             p = jnp.exp(logits(j) - m[..., None])
             return jnp.sum(p, axis=-1), jnp.einsum(
-                "kgp,pkd->kgd", p, vpf[tab_row[j]],
+                f"kgp,{pkd}->kgd", p, vpf[tab_row[j]],
                 preferred_element_type=jnp.float32,
                 precision=jax.lax.Precision.HIGHEST)
 
@@ -336,6 +345,7 @@ def flash_attention_decode(
     *,
     block: int,
     scale=None,
+    heads_major: bool = False,
 ) -> jax.Array:
     """Oracle for ``fused.flash_attention_pallas``.
 
@@ -344,18 +354,25 @@ def flash_attention_decode(
     (b, j) and the page at table entry (b, j) are the same (block, KV,
     hd) array, and the kernels walk them with identical contractions,
     masks, and phase order.  Delegating to ``paged_attention_decode``
-    therefore reuses its proven bitwise form verbatim.
+    therefore reuses its proven bitwise form verbatim, with the chunks
+    laid out head-major where the kernel reads them so (``heads_major``).
     """
     b, seq, kv_heads, hd = k.shape
     if seq % block:
         raise ValueError(f"cache length {seq} not divisible by "
                          f"block {block}")
     n_blocks = seq // block
-    kp = k.reshape(b * n_blocks, block, kv_heads, hd)
-    vp = v.reshape(b * n_blocks, block, kv_heads, hd)
+
+    def pages(x):
+        x = x.reshape(b, n_blocks, block, kv_heads, hd)
+        if heads_major:
+            x = jnp.swapaxes(x, 2, 3)
+        return x.reshape((b * n_blocks,) + x.shape[2:])
+
     tab = (jnp.arange(b, dtype=jnp.int32)[:, None] * n_blocks
            + jnp.arange(n_blocks, dtype=jnp.int32)[None, :])
-    return paged_attention_decode(q, kp, vp, tab, kv_len, scale=scale)
+    return paged_attention_decode(q, pages(k), pages(v), tab, kv_len,
+                                  scale=scale, heads_major=heads_major)
 
 
 def analog_mvm_parasitic_diff(

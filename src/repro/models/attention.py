@@ -124,11 +124,16 @@ def attention_block(
         # decode: insert the new token(s) at cache_len, attend over the cache
         if getattr(cache_len, "ndim", 0):
             # per-slot fills (continuous batching): each row writes its
-            # token(s) at its own offset via a batched scatter
-            rows = jnp.arange(b)[:, None]
-            pos = cache_len[:, None] + jnp.arange(s)[None, :]
-            ck = cache["k"].at[rows, pos].set(k)
-            cv = cache["v"].at[rows, pos].set(v)
+            # token(s) at its own offset, as selects over the cache that
+            # the compiler fuses into the layer's slice of the stacked
+            # cache in the layout that has (a scatter would relayout the
+            # cache on a TPU, twice a layer)
+            off = (jnp.arange(cache["k"].shape[1])[None, :]
+                   - cache_len[:, None])[:, :, None, None]
+            ck, cv = cache["k"], cache["v"]
+            for i in range(s):
+                ck = jnp.where(off == i, k[:, i:i + 1].astype(ck.dtype), ck)
+                cv = jnp.where(off == i, v[:, i:i + 1].astype(cv.dtype), cv)
         else:
             ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k,
                                                      cache_len, 1)

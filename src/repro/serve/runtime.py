@@ -328,6 +328,14 @@ class ServeRuntime:
         self._root_key = jax.random.PRNGKey(seed)
         self._decode_fn = jax.jit(self._make_decode_fn())
         self._prefill_fns: Dict[Tuple[int, int], Any] = {}
+        # cache positions per flash-decode chunk, as the kernel chooses
+        # them (``None``: the decode step reads no chunks)
+        self._attn_block = None
+        if attn_backend == "flash":
+            from repro.kernels.ops import flash_decode_block
+
+            self._attn_block = flash_decode_block(
+                self.max_len, cfg.n_kv_heads, cfg.hd, cfg.dtype)
         self._next_uid = 0
         self.reset()
 
@@ -358,7 +366,8 @@ class ServeRuntime:
                        "occupancy_sum": 0, "tokens_out": 0, "ttft_s": [],
                        "heal_events": 0, "bands_reprogrammed": 0,
                        "recalibrations": 0, "probe_losses": [],
-                       "device_syncs": 0}
+                       "device_syncs": 0, "attn_blocks_read": 0,
+                       "attn_blocks_cached": 0}
         for phase in PHASES:
             self._stats[phase + "_s"] = 0.0
             self._stats[phase + "_max_s"] = 0.0
@@ -395,7 +404,14 @@ class ServeRuntime:
         * ``device_syncs``: blocking reads of device state — each collect
           that read the slot state (a step on which a lane may finish;
           every step with EOS stopping on), each ``measure_ttft`` wait
-          and each :meth:`stream`.
+          and each :meth:`stream`;
+        * ``attn_blocks_read``, ``attn_blocks_cached``: with
+          ``attn_backend="flash"``, the flash-decode chunks of each live
+          lane at each decode step up to its fill, ``ceil(fill / block)``,
+          and of its whole cache (0 with any other backend).  Their ratio
+          is the share of the cache the kernel reads.  Counted from the
+          host's bookkeeping, so exact while the budget is the only stop
+          condition (an upper bound with EOS stopping on).
         """
         s = dict(self._stats)
         s["ttft_s"] = list(s["ttft_s"])      # snapshot, not the live list
@@ -477,15 +493,31 @@ class ServeRuntime:
             # with budget left is device-side knowledge; _collect (which
             # syncs every step when EOS is on) frees that slot one step
             # later.
-            live = sum(p is not None and p.done_step > t
-                       for p in self._slots)
+            lanes = [p for p in self._slots
+                     if p is not None and p.done_step > t]
+            live = len(lanes)
             span.set_metadata(live=live)
             if live:
                 with self._phase("decode"):
                     self._run_decode()
                 self._stats["decode_steps"] += 1
                 self._stats["occupancy_sum"] += live
+                self._count_attn_blocks(lanes, t)
             return early + self._collect()
+
+    def _count_attn_blocks(self, lanes: List[_Pending], t: int) -> None:
+        """Add decode step ``t``'s flash-decode chunks to the counters.  A
+        lane retiring at step ``done_step`` decodes its last token with
+        ``prompt + max_new - 1`` positions filled, one fewer each step
+        before."""
+        blk = self._attn_block
+        if blk is None:
+            return
+        for p in lanes:
+            fill = p.prompt.size + p.max_new - (p.done_step - t)
+            self._stats["attn_blocks_read"] += -(-fill // blk)
+        self._stats["attn_blocks_cached"] += len(lanes) * -(-self.max_len
+                                                            // blk)
 
     @contextlib.contextmanager
     def _phase(self, name: str, counted: bool = True, **args):

@@ -523,44 +523,100 @@ def test_fused_mvm_parasitic_matches_oracle(m, p, s, rows, n):
 # flash-decode attention over the dense per-slot KV cache
 # ---------------------------------------------------------------------------
 
-def _flash_case(b, s, kv, g, hd, seed=0):
+def _flash_case(b, s, kv, g, hd, seed=0, dtype=jnp.float32, fills=None):
     h = kv * g
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)
-    ck = jax.random.normal(ks[1], (b, s, kv, hd), jnp.float32)
-    cv = jax.random.normal(ks[2], (b, s, kv, hd), jnp.float32)
-    rng = np.random.default_rng(seed)
-    fills = jnp.asarray(rng.integers(1, s + 1, size=b), jnp.int32)
-    return q, ck, cv, fills
+    ck = jax.random.normal(ks[1], (b, s, kv, hd), jnp.float32).astype(dtype)
+    cv = jax.random.normal(ks[2], (b, s, kv, hd), jnp.float32).astype(dtype)
+    if fills is None:
+        fills = np.random.default_rng(seed).integers(1, s + 1, size=b)
+    return q, ck, cv, jnp.asarray(fills, jnp.int32)
 
 
-@pytest.mark.parametrize("b,s,kv,g,hd", [
-    (1, 8, 2, 1, 8),     # single row, single group
-    (2, 16, 2, 2, 8),
-    (3, 40, 2, 1, 32),   # cache off the block multiple
-    (4, 33, 4, 2, 16),
-    (2, 9, 1, 4, 8),     # GQA onto one KV head
+@pytest.mark.parametrize("b,s,kv,g,hd,block,dtype,fills", [
+    pytest.param(1, 8, 2, 1, 8, 8, jnp.float32, None,
+                 id="1-8-2-1-8"),      # single row, single group
+    pytest.param(2, 16, 2, 2, 8, 8, jnp.float32, None, id="2-16-2-2-8"),
+    pytest.param(3, 40, 2, 1, 32, 8, jnp.float32, None,
+                 id="3-40-2-1-32"),    # cache off the block multiple
+    pytest.param(4, 33, 4, 2, 16, 8, jnp.float32, None, id="4-33-4-2-16"),
+    pytest.param(2, 9, 1, 4, 8, 8, jnp.float32, None,
+                 id="2-9-1-4-8"),      # GQA onto one KV head
+    # the cache as served: bfloat16, upcast inside the kernel
+    pytest.param(3, 40, 2, 1, 32, 8, jnp.bfloat16, None, id="bf16"),
+    pytest.param(3, 48, 2, 2, 16, 16, jnp.bfloat16, None, id="bf16-gqa"),
+    # fills at the chunk edges: 1, one chunk, one chunk + 1, the whole S
+    pytest.param(4, 32, 2, 2, 8, 8, jnp.float32, [1, 8, 9, 32],
+                 id="fills-at-chunk-edges"),
+    pytest.param(4, 48, 2, 1, 16, 16, jnp.bfloat16, [1, 16, 17, 48],
+                 id="bf16-fills-at-chunk-edges"),
+    # a KV count the TPU lays out head-major: the kernel reads that view
+    pytest.param(3, 32, 5, 2, 8, 8, jnp.float32, [1, 8, 29],
+                 id="heads-major"),
+    pytest.param(2, 48, 6, 1, 16, 16, jnp.bfloat16, None,
+                 id="heads-major-bf16"),
+    # block=None: the shape rule (one chunk at these sizes)
+    pytest.param(3, 40, 2, 1, 32, None, jnp.float32, None, id="shape-rule"),
+    pytest.param(4, 33, 4, 2, 16, None, jnp.bfloat16, None,
+                 id="shape-rule-bf16-padded"),
 ])
-def test_flash_decode_bitwise_vs_oracle(b, s, kv, g, hd):
+def test_flash_decode_bitwise_vs_oracle(b, s, kv, g, hd, block, dtype,
+                                        fills):
     """Same two-phase exactness anchor as the paged kernel: the flash
     decode kernel is BITWISE equal to its chunked-gather oracle on
     ragged fills — what the fused runtime's token agreement rests on."""
-    q, ck, cv, fills = _flash_case(b, s, kv, g, hd, seed=b * 7 + s)
-    out = ops.flash_attention_decode(q, ck, cv, fills, backend="kernel")
-    want = ops.flash_attention_decode(q, ck, cv, fills, backend="oracle")
+    q, ck, cv, fills = _flash_case(b, s, kv, g, hd, seed=b * 7 + s,
+                                   dtype=dtype, fills=fills)
+    out = ops.flash_attention_decode(q, ck, cv, fills, block=block,
+                                     backend="kernel")
+    want = ops.flash_attention_decode(q, ck, cv, fills, block=block,
+                                      backend="oracle")
     assert out.shape == (b, kv * g, hd)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
 def test_flash_decode_invariant_to_cache_tail():
     """Positions >= kv_len[b] are exact zeros in both phases: garbage in
-    the unwritten tail of the dense cache cannot leak into the output."""
-    q, ck, cv, fills = _flash_case(3, 16, 2, 2, 8, seed=9)
-    base = np.asarray(ops.flash_attention_decode(q, ck, cv, fills))
-    ckg, cvg = np.asarray(ck).copy(), np.asarray(cv).copy()
-    for i, n in enumerate(np.asarray(fills)):
-        ckg[i, int(n):] = 1e9
-        cvg[i, int(n):] = -1e9
-    out = ops.flash_attention_decode(q, jnp.asarray(ckg), jnp.asarray(cvg),
-                                     fills)
-    np.testing.assert_array_equal(base, np.asarray(out))
+    the unwritten tail of the dense cache, in the row's last chunk and in
+    whole chunks past it, cannot leak into the output."""
+    for kv in (2, 5):                    # both views of the cache
+        q, ck, cv, fills = _flash_case(3, 32, kv, 2, 8, seed=9,
+                                       fills=[3, 8, 21])
+        base = np.asarray(ops.flash_attention_decode(q, ck, cv, fills,
+                                                     block=8))
+        ckg, cvg = np.asarray(ck).copy(), np.asarray(cv).copy()
+        for i, n in enumerate(np.asarray(fills)):
+            ckg[i, int(n):] = 1e9
+            cvg[i, int(n):] = -1e9
+        out = ops.flash_attention_decode(q, jnp.asarray(ckg),
+                                         jnp.asarray(cvg), fills, block=8)
+        np.testing.assert_array_equal(base, np.asarray(out))
+
+
+@pytest.mark.parametrize("seq,kv,hd", [(768, 20, 128), (768, 8, 128),
+                                       (4096, 8, 128), (8 * 97, 20, 128)],
+                         ids=["qwen1.5-4b", "gqa", "gqa-long", "prime"])
+def test_flash_decode_block_divides_the_cache_within_budget(seq, kv, hd):
+    """The chunk rule: a multiple of 8 dividing the cache (no pad copy)
+    whose double-buffered bfloat16 K and V blocks fit the budget — the
+    largest such, so 8 where the cache has no larger divisor in it."""
+    blk = ops.flash_decode_block(seq, kv, hd, jnp.bfloat16)
+    per_pos = 2 * 2 * kv * hd * 2
+    assert blk % 8 == 0 and seq % blk == 0
+    assert blk * per_pos <= ops.FLASH_DECODE_KV_VMEM_BYTES
+    larger = [d for d in range(blk + 8, seq + 1, 8) if seq % d == 0]
+    assert not larger or larger[0] * per_pos > ops.FLASH_DECODE_KV_VMEM_BYTES
+    if seq == 8 * 97:
+        assert blk == 8
+    else:
+        assert blk >= 128
+
+
+def test_flash_decode_block_of_a_ragged_cache_pads_under_one_chunk():
+    """A cache length off the multiple of 8 is padded to the next one,
+    and no further: the chunk divides the padded length."""
+    assert ops.flash_decode_block(33, 4, 16, jnp.float32) == 40
+    assert ops.flash_decode_block(9, 1, 8, jnp.bfloat16) == 16
+    assert ops.flash_decode_block(768, 20, 128, jnp.float32) < \
+        ops.flash_decode_block(768, 20, 128, jnp.bfloat16)

@@ -302,6 +302,24 @@ def test_cache_slot_insert_and_evict(lm):
     assert not np.asarray(ev["layers"]["attn"]["k"]).any()
 
 
+def test_per_slot_decode_writes_each_rows_tokens_at_its_fill(lm):
+    """A decode over per-slot fills writes row ``b``'s tokens at positions
+    ``len[b] ..`` of its own cache rows and nowhere else; a position past
+    the cache's end is dropped."""
+    cfg, params = lm
+    c0 = transformer.init_cache(cfg, 3, 12)
+    cache = {"layers": jax.tree.map(lambda a: a + 0.5, c0["layers"]),
+             "len": jnp.asarray([0, 4, 11], jnp.int32)}
+    toks = jnp.asarray([[3, 5], [7, 9], [11, 13]], jnp.int32)
+    _, out = transformer.decode_step(cfg, params, toks, cache)
+    for name in ("k", "v"):
+        new = np.asarray(out["layers"]["attn"][name])
+        old = np.asarray(cache["layers"]["attn"][name])
+        changed = [np.flatnonzero((new[:, r] != old[:, r]).any(axis=(0, 2, 3)))
+                   .tolist() for r in range(3)]
+        assert changed == [[0, 1], [4, 5], [11]], (name, changed)
+
+
 def test_prefill_ragged_matches_exact_prefill(lm):
     cfg, params = lm
     tokens = (jnp.arange(6, dtype=jnp.int32) % cfg.vocab)[None, :]

@@ -11,11 +11,13 @@ import glob
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
 from repro.configs import get_smoke_config
+from repro.kernels import ops
 from repro.models.registry import get_model
 from repro.serve import ServeRuntime
 from repro.serve.paged import PagedServeRuntime
@@ -207,3 +209,35 @@ def test_every_blocking_read_is_a_device_sync(lm, tmp_path, eos, ttft):
         assert all(synced)
     assert s["device_syncs"] == sum(synced) + (
         s["prefill_calls"] if ttft else 0)
+
+
+def test_attn_block_counters_follow_the_requests(lm, monkeypatch):
+    """``attn_blocks_read`` and ``attn_blocks_cached`` equal what each
+    request's prompt and budget imply: a request decodes ``max_new - 1``
+    steps at fills ``prompt + 1 .. prompt + max_new - 1``.  Counted on the
+    host, they add no device read; ``reset()`` zeroes them."""
+    cfg, params = lm
+    block = 8                             # three chunks in a 24-position cache
+    per_pos = 4 * cfg.n_kv_heads * cfg.hd * jnp.dtype(cfg.dtype).itemsize
+    monkeypatch.setattr(ops, "FLASH_DECODE_KV_VMEM_BYTES", block * per_pos)
+    assert ops.flash_decode_block(24, cfg.n_kv_heads, cfg.hd,
+                                  cfg.dtype) == block
+    reqs = _requests(cfg, seed=3)
+    read = sum(-(-(prompt.size + i) // block)
+               for _, prompt, new in reqs for i in range(1, new))
+    cached = sum(new - 1 for _, _, new in reqs) * (24 // block)
+    assert read < cached
+
+    stats = {}
+    for backend in ("stream", "flash"):
+        rt = ServeRuntime(cfg, params, max_slots=4, max_len=24,
+                          attn_backend=backend)
+        _serve(rt, reqs)
+        stats[backend] = rt.stats
+    assert stats["flash"]["attn_blocks_read"] == read
+    assert stats["flash"]["attn_blocks_cached"] == cached
+    assert stats["stream"]["attn_blocks_read"] == 0
+    assert stats["stream"]["attn_blocks_cached"] == 0
+    assert stats["flash"]["device_syncs"] == stats["stream"]["device_syncs"]
+    rt.reset()
+    assert rt.stats["attn_blocks_read"] == rt.stats["attn_blocks_cached"] == 0

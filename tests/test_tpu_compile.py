@@ -179,6 +179,37 @@ def test_flash_attention_decode_compiles(one_chip):
     _assert_tagged(text, "flash_decode")
 
 
+@pytest.mark.parametrize("kv,g", [(HEADS, 1), (8, 5)], ids=["mha", "gqa"])
+def test_flash_decode_reads_the_stored_cache_at_serving_shape(one_chip,
+                                                              kv, g):
+    """The chat cell's decode shape (16 slots of 768 positions, 20 heads,
+    bfloat16 cache) and qwen3-14b's grouping (8 KV heads of 5 queries):
+    the kernel takes the cache as stored, in the layout the chip stores
+    it in, so nothing outside the kernel makes a cache-sized array (no
+    float32 copy, no relayout; a bitcast is free), and its first operand is the int32 fills that the
+    benchmark's trace reader matches (``custom-call(s32[``)."""
+    batch, cache = 16, 768
+    kv_shape = (batch, cache, kv, HEAD_DIM)
+
+    def f(q, k, v, fills):
+        return ops.flash_attention_decode(q, k, v, fills, interpret=False)
+
+    shapes = [((batch, kv * g, HEAD_DIM), jnp.bfloat16),
+              (kv_shape, jnp.bfloat16), (kv_shape, jnp.bfloat16),
+              ((batch,), jnp.int32)]
+    text = _compile_text(f, shapes, one_chip)
+    _assert_tagged(text, "flash_decode")
+    first = re.findall(r"tpu_custom_call\", operand_layout_constraints="
+                       r"\{(\w+\[)", text)
+    assert first == ["s32["], first
+    dims = ",".join(map(str, sorted(kv_shape)))
+    for line in text.splitlines():
+        shape = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]", line)
+        if shape and not re.search(r" (parameter|bitcast)\(", line):
+            assert ",".join(sorted(shape[2].split(","), key=int)) != dims, \
+                line
+
+
 def test_paged_attention_compiles(one_chip):
     page, per_row = 16, 32
 
