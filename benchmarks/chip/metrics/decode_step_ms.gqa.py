@@ -1,0 +1,6 @@
+"""``decode_step_ms`` on the grouped-query cell (``qwen3-14b.chat``), read as
+``metrics/decode_step_ms.py`` reads it."""
+
+from chipbench import spec
+
+read = spec.metric_reader("decode_step_ms")

@@ -327,9 +327,15 @@ def _apply_line(
 ) -> jax.Array:
     """Per-plane analog dot products -> (B, S, P, M, N)."""
     if not spec.parasitics_on:
-        return jnp.einsum(
-            "bmpr,sprn->bspmn", planes, g, precision=jax.lax.Precision.HIGHEST
-        )
+        # One product per partition, each reading its (rows, N) array as
+        # stored.  As one einsum batched over P, the TPU compiler lays the
+        # weights out rows-minor for calibration's many rows and, inside a
+        # layer scan, relays the whole stacked pack out before the loop: a
+        # second copy of every conductance (7.3 GB at qwen3-14b's 4 layers).
+        return jnp.stack([
+            jnp.einsum("bmr,srn->bsmn", planes[:, :, i], g[:, i],
+                       precision=jax.lax.Precision.HIGHEST)
+            for i in range(planes.shape[2])], axis=2)
     b, m_, p, rows = planes.shape
     s, _, _, n = g.shape
 

@@ -502,7 +502,11 @@ def flash_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
         name="flash_decode",
-        metadata={"kernel": "flash_decode"},
+        # the cache layout the kernel reads (``ops.flash_decode_heads_major``)
+        # and the query heads per KV head, for a trace to tell the paths apart
+        metadata={"kernel": "flash_decode",
+                  "layout": "heads_major" if heads_major else "row_major",
+                  "group": g},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,

@@ -35,7 +35,10 @@ The scheduler loop (one :meth:`ServeRuntime.step`):
 
 1. **admit** — pop waiting requests into free slots; one ragged-prefill
    call per prompt bucket writes their K/V rows, first sampled token,
-   fill lengths and keys into the slot state;
+   fill lengths and keys into the slot state.  Every prefill stalls each
+   live stream for its length, so a lone queued request waits (at most
+   ``coalesce_steps`` steps) for a lane that retires by then, and shares
+   its admission with that lane's successor;
 2. **decode** — one jitted ``decode_step`` over all ``max_slots`` slots
    (finished/free slots ride along masked), sample per-slot, append to
    per-slot output buffers, retire slots that hit a stop condition;
@@ -169,6 +172,8 @@ class _Pending:
     # EOS stopping is off (the budget is the only stop condition), which
     # lets _collect skip device syncs on steps where nothing can finish.
     done_step: int = 0
+    # decode-step counter value when the request was queued
+    queued_step: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -232,6 +237,14 @@ class ServeRuntime:
                disables EOS stopping (pure ``max_new_tokens`` budget).
     gang:      static-batching mode (admit only into an all-free server,
                one shared bucket) — the servebench baseline.
+    coalesce_steps: admission coalescing.  A prefill stalls every live
+               stream for its length, one stall per admitting step.
+               When one request is queued and a live lane retires within
+               this many decode steps, the request waits (at most this
+               many steps) so that the retiring lane's successor is
+               admitted in the same step: one stall for both.  It costs
+               the held request up to this many steps of time to first
+               token.  ``0`` admits at once.
     attn_backend: decode-step attention implementation. ``"stream"`` is
                the online-softmax lax.scan; ``"flash"`` the flash-decode
                Pallas kernel over the dense slot cache
@@ -260,6 +273,7 @@ class ServeRuntime:
         eos_id: Optional[int] = None,
         seed: int = 0,
         gang: bool = False,
+        coalesce_steps: int = 12,
         measure_ttft: bool = False,
         attn_backend: str = "stream",
         manager=None,
@@ -321,6 +335,10 @@ class ServeRuntime:
         self.cfg, self.params, self.pack = cfg, params, pack
         self.max_slots, self.max_len = int(max_slots), int(max_len)
         self.buckets, self.sampler, self.gang = buckets, sampler, gang
+        if coalesce_steps < 0:
+            raise ValueError(
+                f"coalesce_steps must be >= 0, got {coalesce_steps}")
+        self.coalesce_steps = int(coalesce_steps)
         self.measure_ttft = measure_ttft
         self._api = api
         self._eos_enabled = eos_id is not None
@@ -459,7 +477,8 @@ class ServeRuntime:
             raise ValueError(f"request uid {uid!r} is already in flight")
         self._live_uids.add(str(uid))
         self._queue.append(_Pending(uid, prompt, int(max_new_tokens),
-                                    time.perf_counter()))
+                                    time.perf_counter(),
+                                    queued_step=self._stats["decode_steps"]))
         return uid
 
     @property
@@ -638,6 +657,8 @@ class ServeRuntime:
             return False
         if self.gang and len(free) < self.max_slots:
             return False                # static batching: wait for a full drain
+        if self._hold():
+            return False
         take: List[_Pending] = []
         while self._queue and len(take) < len(free):
             if not self._reserve(self._queue[0]):
@@ -664,6 +685,20 @@ class ServeRuntime:
                              uids=" ".join(str(r.uid) for r, _ in items)):
                 self._prefill_group(key, items)
         return True
+
+    def _hold(self) -> bool:
+        """Whether a lone queued request waits a step to share its
+        admission with the successor of a lane that retires soon
+        (``coalesce_steps``): not once it has waited ``coalesce_steps``
+        steps, and only while a live lane retires within that many."""
+        w = self.coalesce_steps
+        if not w or self.gang or len(self._queue) != 1:
+            return False
+        t = self._stats["decode_steps"]
+        if t - self._queue[0].queued_step >= w:
+            return False
+        return any(p is not None and 0 < p.done_step - t <= w
+                   for p in self._slots)
 
     def _reserve(self, req: _Pending) -> bool:
         """Claim admission resources for the queue head (hook).  False

@@ -620,3 +620,25 @@ def test_flash_decode_block_of_a_ragged_cache_pads_under_one_chunk():
     assert ops.flash_decode_block(9, 1, 8, jnp.bfloat16) == 16
     assert ops.flash_decode_block(768, 20, 128, jnp.float32) < \
         ops.flash_decode_block(768, 20, 128, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("kv,g,layout", [(20, 1, "heads_major"),
+                                         (8, 5, "row_major")],
+                         ids=["qwen1.5-4b", "qwen3-14b"])
+def test_flash_decode_tags_its_layout_and_grouping(kv, g, layout):
+    """The compiled kernel's metadata names the cache layout it reads
+    (``ops.flash_decode_heads_major``) and the query heads per KV head, at
+    the chat cells' shape (16 slots of 768 positions, bfloat16 cache), so
+    that a trace tells the two paths apart.  Traced, not run: the
+    metadata is a parameter of the Pallas call."""
+    def f(q, k, v, fills):
+        return ops.flash_attention_decode(q, k, v, fills, interpret=False)
+
+    cache = jax.ShapeDtypeStruct((16, 768, kv, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(f)(
+        jax.ShapeDtypeStruct((16, kv * g, 128), jnp.bfloat16), cache, cache,
+        jax.ShapeDtypeStruct((16,), jnp.int32))
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert dict(calls[0].params["metadata"]) == {
+        "kernel": "flash_decode", "layout": layout, "group": g}

@@ -86,6 +86,81 @@ def test_gang_mode_serves_identically(lm):
         np.testing.assert_array_equal(outs[False][uid], outs[True][uid])
 
 
+def _closed_loop(rt, waves):
+    """Serve ``len(waves[0])`` closed-loop clients: client ``c`` sends
+    ``waves[k][c]`` once its ``k-1``-th request completes.  Returns the
+    outputs and how many steps admitted (ran a prefill)."""
+    out, nxt, admitting = {}, {}, 0
+    for c, (p, n) in enumerate(waves[0]):
+        nxt[rt.submit(p, max_new_tokens=n, uid=(0, c))] = 1
+    while not rt.idle:
+        calls = rt.stats["prefill_calls"]
+        for done in rt.step():
+            out[done.uid] = done.tokens
+            k, c = nxt.pop(done.uid), done.uid[1]
+            if k < len(waves):
+                p, n = waves[k][c]
+                nxt[rt.submit(p, max_new_tokens=n, uid=(k, c))] = k + 1
+        admitting += rt.stats["prefill_calls"] > calls
+    return out, admitting
+
+
+def test_coalesced_admissions_stall_fewer_steps_and_serve_identically(lm):
+    """Lanes retiring two steps apart: with coalescing, each retiring
+    pair's successors share one admitting step (one prefill group, as
+    their prompts share a bucket); the tokens are the same."""
+    cfg, params = lm
+    rng = np.random.default_rng(7)
+
+    def req(n):
+        return rng.integers(0, cfg.vocab, size=5).astype(np.int32), n
+
+    waves = [[req(n) for n in (4, 6, 12, 14)],
+             [req(n) for n in (5, 5, 5, 5)]]
+    got = {}
+    for w in (0, 4):
+        rt = ServeRuntime(cfg, params, max_slots=4, max_len=32,
+                          coalesce_steps=w)
+        got[w] = _closed_loop(rt, waves)
+    assert sorted(got[0][0]) == sorted(got[4][0])
+    for uid, toks in got[0][0].items():
+        np.testing.assert_array_equal(toks, got[4][0][uid])
+    assert got[0][1] == 5 and got[4][1] == 3
+
+
+@pytest.mark.parametrize("budgets,held", [((3, 30), 1), ((9, 30), 0),
+                                          ((2, 4, 6, 30), 4)],
+                         ids=["until-retired", "too-far", "at-most-w"])
+def test_coalescing_holds_a_lone_request_at_most_coalesce_steps(
+        lm, budgets, held):
+    """A lone request with a free slot waits for a lane retiring within
+    ``coalesce_steps`` (4) steps, never longer than that, and not at all
+    when none retires that soon."""
+    cfg, params = lm
+    rt = ServeRuntime(cfg, params, max_slots=len(budgets) + 1, max_len=48,
+                      coalesce_steps=4)
+    for i, n in enumerate(budgets):
+        rt.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=n, uid=i)
+    rt.step()
+    rt.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2, uid="x")
+    steps = 0
+    while rt.stats["prefill_calls"] == 1 and steps < 40:
+        rt.step()
+        steps += 1
+    assert steps - 1 == held           # steps held before the admitting one
+
+
+def test_coalescing_waits_for_nothing_on_an_idle_server(lm):
+    cfg, params = lm
+    rt = ServeRuntime(cfg, params, max_slots=4, max_len=32)
+    assert rt.coalesce_steps > 0
+    rt.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=3, uid=0)
+    rt.step()
+    assert rt.stats["prefill_calls"] == 1
+    with pytest.raises(ValueError, match="coalesce_steps"):
+        ServeRuntime(cfg, params, max_slots=2, max_len=16, coalesce_steps=-1)
+
+
 # ---------------------------------------------------------------------------
 # sampling
 # ---------------------------------------------------------------------------

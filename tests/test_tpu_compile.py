@@ -6,7 +6,9 @@ these tests lower each kernel of the main serving path through Mosaic
 for one chip of a ``v5e:2x2`` topology that is described, not attached,
 at the widths qwen1.5-4b serves: d_model 2560, d_ff 6912, 20 heads of
 128, decode batch 8, and Design A's ``max_rows=1152`` partitioning
-(K = 2560 -> 3 partitions of 854 rows, K = 6912 -> 6 of 1152).
+(K = 2560 -> 3 partitions of 854 rows, K = 6912 -> 6 of 1152).  One
+case compiles a whole decode step: the ``qwen3-14b.chat`` cell's, with
+its memory, its flash-decode layout and the copies it makes.
 
 Each compiled kernel also carries its tag: the Pallas call's ``name=``
 names the instruction and its ``metadata=`` appears in the op's own text
@@ -54,11 +56,17 @@ def _compile_text(fn, shapes, sharding) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _tagged_calls(text: str):
+    """``(instruction name, kernel metadata)`` of every tagged Mosaic call
+    in ``text``."""
+    return re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+                      r"[^\n]*kernel_metadata=\{\n(.*?)\n\}\}", text, re.S)
+
+
 def _assert_tagged(text: str, kernel: str, site=None) -> None:
     """Every Mosaic call in ``text`` is ``kernel``'s, named after it and
     tagged with it (and with its fused site class)."""
-    calls = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\""
-                       r"[^\n]*kernel_metadata=\{\n(.*?)\n\}\}", text, re.S)
+    calls = _tagged_calls(text)
     assert calls, "no tagged tpu_custom_call in the compiled text"
     for name, meta in calls:
         assert name.startswith(kernel + "."), name
@@ -224,3 +232,72 @@ def test_paged_attention_compiles(one_chip):
     text = _compile_text(f, shapes, one_chip)
     assert "tpu_custom_call" in text
     _assert_tagged(text, "paged_attention")
+
+
+def test_qwen3_decode_step_fits_and_reads_the_cache_row_major(one_chip,
+                                                              monkeypatch):
+    """The ``qwen3-14b.chat`` cell's whole decode step, as
+    ``ServeRuntime`` jits it: Qwen3-14B's published widths (5120, 40 query
+    heads over 8 KV heads of 128, d_ff 17408, per-head q/k norm) on 4
+    layers with an eighth of the padded vocabulary (19072), a Design A
+    pack, 16 slots of 768 bfloat16 positions.  What the chip holds while
+    it runs (every weight and conductance, the slot state, the step's
+    output and temporaries) fits its 16 GiB; flash-decode reads the
+    8-head cache row-major, 5 queries to a KV head; and no copy,
+    convert or transpose of a layer's cache, or of the stacked cache, is
+    made outside the kernel."""
+    import dataclasses
+
+    from repro.configs.qwen3_14b import CONFIG
+    from repro.core.errors import ErrorModel
+    from repro.models.registry import get_model
+    from repro.serve import ServeRuntime, calibrate_lm, program_lm
+
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    cfg = dataclasses.replace(CONFIG, n_layers=4, vocab=19072, remat=False)
+    spec = design_a(ErrorModel(kind="state_proportional", alpha=0.02),
+                    fused="kernel")
+    slots, max_len = 16, 768
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    params = jax.eval_shape(
+        lambda k: jax.tree.map(lambda a: a.astype(cfg.dtype),
+                               get_model(cfg).init_params(cfg, k)), key)
+    pack = jax.eval_shape(lambda p, k: program_lm(cfg, p, spec, k),
+                          params, key)
+    pack = jax.eval_shape(
+        lambda p, pk, t: dataclasses.replace(calibrate_lm(cfg, p, pk, t),
+                                             collect=False),
+        params, pack, jax.ShapeDtypeStruct((2, 64), jnp.int32))
+    rt = ServeRuntime(cfg, params, pack=pack, max_slots=slots,
+                      max_len=max_len, buckets=(128, 256),
+                      attn_backend="flash")
+    on_chip = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    state = on_chip(jax.eval_shape(lambda: rt._state))
+    compiled = rt._decode_fn.lower(state, on_chip(params),
+                                   on_chip(pack)).compile()
+
+    nbytes = lambda t: sum(a.size * a.dtype.itemsize
+                           for a in jax.tree.leaves(t))
+    mem = compiled.memory_analysis()
+    held = (nbytes(params) + nbytes(pack) + nbytes(state)
+            + mem.output_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 16 * 2 ** 30, held
+
+    text = compiled.as_text()
+    calls = _tagged_calls(text)
+    flash = [meta for name, meta in calls if name.startswith("flash_decode.")]
+    assert {name.split(".")[0] for name, _ in calls} == {"flash_decode",
+                                                         "fused_mvm"}
+    assert all('"kernel":"flash_decode"' in m and '"layout":"row_major"' in m
+               and '"group":5' in m for m in flash), flash
+    layer = sorted((slots, max_len, cfg.n_kv_heads, cfg.hd))
+    stacked = sorted(layer + [cfg.n_layers])
+    for line in text.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* (\w+)\(",
+                      line)
+        if not op or sorted(int(d) for d in op[2].split(",") if d) not in (
+                layer, stacked):
+            continue
+        assert not re.match(r"(copy|convert|transpose)", op[1]), line
+        assert op[3] not in ("copy", "convert", "transpose"), line
