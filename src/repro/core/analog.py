@@ -168,7 +168,14 @@ def design_e(error: Optional[ErrorModel] = None, **kw) -> AnalogSpec:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class AnalogWeights:
-    """Programmed conductances + dequantization metadata for one matrix."""
+    """Programmed conductances + dequantization metadata for one matrix.
+
+    As stored, ``layer`` is ``None``.  Inside a layer scan a site whose
+    stack the fused kernel reads in place (:func:`reads_stacked`) keeps
+    its layer-stacked ``g_pos``/``g_neg`` ``(L, S, P, rows, N)`` and
+    carries the scan's traced layer index here instead; every other path
+    reads :meth:`at_layer`.
+    """
 
     g_pos: jax.Array                 # (S, P, rows, N)
     g_neg: Optional[jax.Array]       # (S, P, rows, N) | None
@@ -176,6 +183,15 @@ class AnalogWeights:
     w_scale: jax.Array               # scalar quant scale
     k: int = dataclasses.field(metadata=dict(static=True))
     n: int = dataclasses.field(metadata=dict(static=True))
+    layer: Optional[jax.Array] = None   # int32 index into stacked g_pos/g_neg
+
+    def at_layer(self) -> "AnalogWeights":
+        """These weights with ``layer``'s slice of each stacked array."""
+        if self.layer is None:
+            return self
+        pick = lambda g: None if g is None else g[self.layer]
+        return dataclasses.replace(self, g_pos=pick(self.g_pos),
+                                   g_neg=pick(self.g_neg), layer=None)
 
 
 def _partition(arr: jax.Array, k: int, p: int, rows: int) -> jax.Array:
@@ -395,6 +411,24 @@ def _maybe_pallas_fastpath(spec: AnalogSpec, collect: bool) -> bool:
     )
 
 
+def reads_stacked(spec: AnalogSpec, aw: AnalogWeights, *,
+                  collect: bool = False) -> bool:
+    """Whether :func:`analog_matmul` hands this site's layer-stacked
+    conductances whole to the fused Pallas kernel, which picks the layer
+    itself (``AnalogWeights.layer``).  So it does where the call reaches
+    the kernel (``fused="kernel"``, the fast path, linear or parasitic)
+    and the kernel reads the stack as stored
+    (``kernels.ops.fused_reads_stacked``: float32, ``rows % 8 == 0``,
+    ``N`` whole lane tiles).  Calibration, the oracle and composed paths,
+    other dtypes and 854-row arrays read a per-layer slice."""
+    from repro.kernels import ops as kops
+
+    return (spec.fused == "kernel"
+            and _maybe_pallas_fastpath(spec, collect)
+            and aw.g_pos.shape[-1] == aw.n
+            and kops.fused_reads_stacked(aw.g_pos))
+
+
 def fuse_signature(spec: AnalogSpec) -> Optional[Tuple]:
     """The static compile identity of a spec's fused serving kernel.
 
@@ -462,7 +496,10 @@ def analog_matmul(
     gain = lmax / (1.0 - m.g_min)          # conductance -> code units
     slice_w = 2.0 ** (m.cell_bits * jnp.arange(m.n_slices, dtype=x.dtype))
 
-    if _maybe_pallas_fastpath(spec, collect) and adc_lo is not None:
+    fast = _maybe_pallas_fastpath(spec, collect) and adc_lo is not None
+    if not (fast and spec.fused != "off"):
+        aw = aw.at_layer()                 # the fused wrappers take the index
+    if fast:
         from repro.kernels import ops as kops
 
         if spec.fused != "off":
@@ -475,27 +512,29 @@ def analog_matmul(
             if spec.parasitics_on:
                 y = kops.fused_mvm_parasitic(
                     x_parts,
-                    aw.g_pos[:, :, :, : aw.n], aw.g_neg[:, :, :, : aw.n],
+                    aw.g_pos[..., : aw.n], aw.g_neg[..., : aw.n],
                     r_hat=spec.r_hat, adc_lo=adc_lo, adc_hi=adc_hi,
                     adc_bits=spec.adc.bits, cell_bits=m.cell_bits,
                     n_bits=spec.n_planes, scale=scale, backend=backend,
+                    layer=aw.layer,
                 )
             else:
                 n_bits = (None if spec.input_accum == "analog"
                           else spec.n_planes)
                 y = kops.fused_mvm(
                     x_parts,
-                    aw.g_pos[:, :, :, : aw.n], aw.g_neg[:, :, :, : aw.n],
+                    aw.g_pos[..., : aw.n], aw.g_neg[..., : aw.n],
                     adc_lo=adc_lo, adc_hi=adc_hi,
                     adc_bits=spec.adc.bits, cell_bits=m.cell_bits,
                     n_bits=n_bits, scale=scale, backend=backend,
+                    layer=aw.layer,
                 )
             return y.reshape(*lead, aw.n)
 
         if spec.parasitics_on:
             d_codes = kops.analog_mvm_parasitic(
                 x_parts,
-                aw.g_pos[:, :, :, : aw.n], aw.g_neg[:, :, :, : aw.n],
+                aw.g_pos[..., : aw.n], aw.g_neg[..., : aw.n],
                 r_hat=spec.r_hat, n_bits=spec.n_planes,
                 adc_lo=adc_lo, adc_hi=adc_hi, adc_bits=spec.adc.bits,
                 gain=gain,
@@ -503,7 +542,7 @@ def analog_matmul(
         else:
             d_codes = kops.analog_mvm(
                 x_parts,
-                aw.g_pos[:, :, :, : aw.n], aw.g_neg[:, :, :, : aw.n],
+                aw.g_pos[..., : aw.n], aw.g_neg[..., : aw.n],
                 adc_lo=adc_lo, adc_hi=adc_hi, adc_bits=spec.adc.bits,
                 gain=gain,
             )

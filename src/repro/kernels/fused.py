@@ -18,6 +18,12 @@ Grid/BlockSpec layout (both MVM kernels)::
     g blocks (S, 1, rows, bn) index (0, p, 0, j)   # all slices resident
     out      (bm, bn)        index (i, j)  accumulated over p
 
+A layer scan may hand the kernel a site's whole layer stack
+``(L, S, P, rows, N)`` and a traced layer index in place of the layer's
+slice: the g blocks are then ``(-, S, 1, rows, bn)`` at index
+``(l, 0, p, 0, j)``, ``l`` read from a scalar-prefetch operand, and the
+grid, tiles and arithmetic are unchanged (``_mvm_call``).
+
 The activations are *partition-major* ``(P, M, rows)`` (``-`` is the
 squeezed partition dim): Mosaic requires the last two dims of a block to
 be (8, 128)-aligned or whole, so the ``(bm, rows)`` tile must be the
@@ -264,48 +270,101 @@ def _fused_parasitic_kernel(scale_ref, r_ref, lo_ref, hi_ref, xt_ref,
         o_ref[...] = o_ref[...] * out_scale
 
 
-def _mvm_call(kern, x_in, x_spec, g_pos, g_neg, extra, adc_lo, adc_hi,
-              scale, *, site: tuple, m: int, bm: int, bn: int,
-              interpret: bool):
+def _mvm_call(kern, x_in, x_block, x_index, g_pos, g_neg, extra, adc_lo,
+              adc_hi, scale, *, site: tuple, m: int, bm: int, bn: int,
+              interpret: bool, layer=None):
     """Shared pallas_call plumbing for both fused MVM kernels.
 
     ``x_in`` is the activation operand in the layout its kernel reads,
-    ``x_spec`` its block spec and ``m`` its (padded) row count.
-    ``extra`` is a list of additional leading (1, 1) scalar operands (the
-    parasitic ``r_hat``); ``adc_lo/adc_hi`` are per-slice (S,).  ``site``
-    is the call's site class (a :data:`BUILD_SIGNATURES` entry), recorded
-    here and carried in the kernel's metadata beside ``"kernel":
-    "fused_mvm"``, so a profiler trace names the op and its site class.
+    ``x_block`` its block shape, ``x_index`` its ``(i, j, p) -> index``
+    map and ``m`` its (padded) row count.  ``extra`` is a list of
+    additional leading (1, 1) scalar operands (the parasitic ``r_hat``);
+    ``adc_lo/adc_hi`` are per-slice (S,).  ``site`` is the call's site
+    class (a :data:`BUILD_SIGNATURES` entry), recorded here and carried in
+    the kernel's metadata beside ``"kernel": "fused_mvm"``, so a profiler
+    trace names the op and its site class.
+
+    With ``layer`` (a traced int32 scalar) the conductances are a layer
+    stack ``(L, S, P, rows, N)`` and the kernel picks layer ``layer``
+    itself: its conductance blocks are indexed ``(layer, 0, p, 0, j)``
+    from a scalar-prefetched index, so no per-layer slice of the stack is
+    written out before the call.  The (1, 1) scale is then prefetched too,
+    ahead of the index, and stays the call's first operand.  Blocks,
+    grid and arithmetic are those of the per-layer call, so are the bits;
+    metadata ``"stacked"`` says which of the two a call is.
     """
     BUILD_SIGNATURES.add(site)
-    n_slices, p, rows, n = g_pos.shape
+    n_slices, p, rows, n = g_pos.shape[-4:]
     if m % bm or n % bn:
         raise ValueError(
             f"block shape ({bm}, {bn}) does not tile operand ({m}, {n})")
-    grid = (m // bm, n // bn, p)
     scale2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     lo2 = jnp.asarray(adc_lo, jnp.float32).reshape(n_slices, 1)
     hi2 = jnp.asarray(adc_hi, jnp.float32).reshape(n_slices, 1)
     extra2 = [jnp.asarray(e, jnp.float32).reshape(1, 1) for e in extra]
-    scalar_specs = [pl.BlockSpec((1, 1), lambda i, j, p_: (0, 0))
-                    for _ in range(1 + len(extra2))]
-    slice_specs = [pl.BlockSpec((n_slices, 1), lambda i, j, p_: (0, 0))
-                   for _ in range(2)]
-    g_spec = pl.BlockSpec((n_slices, 1, rows, bn),
+
+    def call(stacked: bool):
+        def spec(block, index):
+            # index maps see the grid, then any scalar-prefetch refs
+            return pl.BlockSpec(block, lambda i, j, p_, *_: index(i, j, p_))
+
+        specs = ([spec((1, 1), lambda i, j, p_: (0, 0)) for _ in extra2]
+                 + [spec((n_slices, 1), lambda i, j, p_: (0, 0))
+                    for _ in range(2)]
+                 + [spec(x_block, x_index)])
+        if stacked:
+            g_spec = pl.BlockSpec(
+                (None, n_slices, 1, rows, bn),
+                lambda i, j, p_, scale_ref, layer_ref:
+                    (layer_ref[0], 0, p_, 0, j))
+            body = lambda scale_ref, layer_ref, *refs: kern(scale_ref, *refs)
+        else:
+            g_spec = spec((n_slices, 1, rows, bn),
                           lambda i, j, p_: (0, p_, 0, j))
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        name="fused_mvm",
-        metadata={"kernel": "fused_mvm", "site": str(site)},
-        in_specs=scalar_specs + slice_specs + [x_spec, g_spec, g_spec],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, p_: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret,
-    )(scale2, *extra2, lo2, hi2, x_in, g_pos, g_neg)
+            specs = [spec((1, 1), lambda i, j, p_: (0, 0))] + specs
+            body = kern
+        grid_kw = dict(grid=(m // bm, n // bn, p),
+                       in_specs=specs + [g_spec, g_spec],
+                       out_specs=spec((bm, bn), lambda i, j, p_: (i, j)))
+        return pl.pallas_call(
+            body,
+            grid_spec=(pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=2,
+                                                    **grid_kw)
+                       if stacked else pl.GridSpec(**grid_kw)),
+            name="fused_mvm",
+            metadata={"kernel": "fused_mvm", "site": str(site),
+                      "stacked": stacked},
+            out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            interpret=interpret,
+        )
+
+    if layer is None:
+        return call(False)(scale2, *extra2, lo2, hi2, x_in, g_pos, g_neg)
+
+    @jax.custom_batching.custom_vmap
+    def stacked_call(layer1, scale2, *operands):
+        return call(True)(scale2, layer1, *operands)
+
+    @stacked_call.def_vmap
+    def _batched(axis_size, in_batched, layer1, scale2, *operands):
+        # Pallas batches a call over a prefetched operand (here the scale,
+        # one per trial) only by a loop that slices every batched operand,
+        # whole stacks included; slicing the layer out first and batching
+        # the per-layer call reads each stack no more than the scan did
+        def one(layer1, scale2, *operands):
+            *rest, gp, gm = operands
+            return call(False)(scale2, *rest, gp[layer1[0]], gm[layer1[0]])
+
+        axes = [0 if b else None for b in in_batched]
+        return jax.vmap(one, in_axes=axes, axis_size=axis_size)(
+            layer1, scale2, *operands), True
+
+    layer1 = jnp.asarray(layer, jnp.int32).reshape(1)
+    return stacked_call(layer1, scale2, *extra2, lo2, hi2, x_in, g_pos,
+                        g_neg)
 
 
 def fused_mvm_pallas(
@@ -322,16 +381,17 @@ def fused_mvm_pallas(
     bm: int = 128,
     bn: int = 128,
     interpret: bool,
+    layer=None,            # traced int32: g_* are (L, S, P, rows, N) stacks
 ) -> jax.Array:
     """Fused differential analog MVM; returns the dequantized (M, N)."""
     kern = functools.partial(_fused_diff_kernel, adc_bits=adc_bits,
                              cell_bits=cell_bits, n_bits=n_bits)
     _, m, rows = x_parts.shape
-    x_spec = pl.BlockSpec((None, bm, rows), lambda i, j, p_: (p_, i, 0))
-    site = ("linear", g_pos.shape[0], cell_bits, adc_bits, n_bits, None)
-    return _mvm_call(kern, x_parts, x_spec, g_pos, g_neg, [], adc_lo,
+    site = ("linear", g_pos.shape[-4], cell_bits, adc_bits, n_bits, None)
+    return _mvm_call(kern, x_parts, (None, bm, rows),
+                     lambda i, j, p_: (p_, i, 0), g_pos, g_neg, [], adc_lo,
                      adc_hi, scale, site=site, m=m, bm=bm, bn=bn,
-                     interpret=interpret)
+                     interpret=interpret, layer=layer)
 
 
 def fused_mvm_parasitic_pallas(
@@ -349,16 +409,17 @@ def fused_mvm_parasitic_pallas(
     bm: int = 128,
     bn: int = 128,
     interpret: bool,
+    layer=None,            # traced int32: g_* are (L, S, P, rows, N) stacks
 ) -> jax.Array:
     """Fused parasitic differential MVM; returns the dequantized (M, N)."""
     _, rows, m = xt_parts.shape
     kern = functools.partial(_fused_parasitic_kernel, adc_bits=adc_bits,
                              cell_bits=cell_bits, n_bits=n_bits, rows=rows)
-    x_spec = pl.BlockSpec((None, rows, bm), lambda i, j, p_: (p_, 0, i))
-    site = ("parasitic", g_pos.shape[0], cell_bits, adc_bits, None, n_bits)
-    return _mvm_call(kern, xt_parts, x_spec, g_pos, g_neg, [r_hat], adc_lo,
-                     adc_hi, scale, site=site, m=m, bm=bm, bn=bn,
-                     interpret=interpret)
+    site = ("parasitic", g_pos.shape[-4], cell_bits, adc_bits, None, n_bits)
+    return _mvm_call(kern, xt_parts, (None, rows, bm),
+                     lambda i, j, p_: (p_, 0, i), g_pos, g_neg, [r_hat],
+                     adc_lo, adc_hi, scale, site=site, m=m, bm=bm, bn=bn,
+                     interpret=interpret, layer=layer)
 
 
 # ---------------------------------------------------------------------------

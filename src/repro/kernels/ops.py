@@ -183,6 +183,31 @@ def paged_attention(
     return out[:, :, :hd].astype(q.dtype)
 
 
+def fused_reads_stacked(g: jax.Array) -> bool:
+    """Whether the fused MVM kernels read a layer-stacked conductance
+    array ``(L, S, P, rows, N)`` as stored, picking their layer in place.
+
+    So it is where the array needs nothing before the kernel: it is
+    float32 (no convert), ``N`` fills whole 128-lane tiles (no pad), and
+    ``rows`` is a multiple of 8, so the TPU tiles it ``(8, 128)`` as the
+    kernel's ``(rows, bn)`` blocks are (an 854-row array it lays out
+    ``T(1, 128)``, which the kernel cannot read).  Handed whole, an array
+    that needs any of these would be converted, padded or relaid out
+    whole, every layer at once; such a stack is sliced per layer instead.
+    """
+    *_, rows, n = g.shape
+    return g.dtype == jnp.float32 and rows % 8 == 0 and n % 128 == 0
+
+
+def _stack_or_slice(g_pos, g_neg, layer, backend: str):
+    """The conductances a fused MVM call reads, and the layer its kernel
+    picks: a stack it reads in place keeps ``layer``; any other (and any
+    oracle call) is sliced to the layer here."""
+    if layer is None or (backend == "kernel" and fused_reads_stacked(g_pos)):
+        return g_pos, g_neg, layer
+    return g_pos[layer], g_neg[layer], None
+
+
 def fused_mvm(
     x_parts: jax.Array,      # (M, P, rows) integer-valued signed
     g_pos: jax.Array,        # (S, P, rows, N)
@@ -196,6 +221,7 @@ def fused_mvm(
     scale,                   # traced scalar: gain * w_scale * x_scale
     backend: str = "kernel",
     interpret: bool | None = None,
+    layer=None,              # traced int32: g_* are (L, S, P, rows, N)
 ) -> jax.Array:
     """Fused analog MVM chain (matmul + ADC + dequant + slice/bit
     shift-and-add in one launch); returns the dequantized (M, N).
@@ -206,17 +232,23 @@ def fused_mvm(
     ``"oracle"`` the bitwise-identical jnp mirror (``kernels.ref``) —
     the composed multi-op form of the same chain, which is what the
     fused serving runtime is agreement-gated against end to end.
+
+    With ``layer`` the conductances are layer stacks and the result is
+    that of layer ``layer``'s slice: the kernel reads a stack it can read
+    as stored (:func:`fused_reads_stacked`) in place, anything else is
+    sliced first.
     """
     if backend not in ("kernel", "oracle"):
         raise ValueError(f"unknown fused_mvm backend {backend!r}")
+    g_pos, g_neg, layer = _stack_or_slice(g_pos, g_neg, layer, backend)
     interpret = _use_interpret() if interpret is None else interpret
     m, p, rows = x_parts.shape
     n = g_pos.shape[-1]
     bm = _pick_tile(m, 128)
     bn = _pick_tile(n, 128, lane=True)
     xp = _pad_to(jnp.swapaxes(x_parts, 0, 1).astype(jnp.float32), 1, bm)
-    gp = _pad_to(g_pos.astype(jnp.float32), 3, bn)
-    gm = _pad_to(g_neg.astype(jnp.float32), 3, bn)
+    gp = _pad_to(g_pos.astype(jnp.float32), g_pos.ndim - 1, bn)
+    gm = _pad_to(g_neg.astype(jnp.float32), g_neg.ndim - 1, bn)
     if backend == "oracle":
         out = _k_ref.fused_mvm_diff(
             xp, gp, gm, adc_lo, adc_hi, scale,
@@ -227,7 +259,7 @@ def fused_mvm(
         out = _k_fused.fused_mvm_pallas(
             xp, gp, gm, adc_lo, adc_hi, scale,
             adc_bits=adc_bits, cell_bits=cell_bits, n_bits=n_bits,
-            bm=bm, bn=bn, interpret=interpret,
+            bm=bm, bn=bn, interpret=interpret, layer=layer,
         )
     return out[:m, :n]
 
@@ -246,21 +278,24 @@ def fused_mvm_parasitic(
     scale,                   # traced scalar: gain * w_scale * x_scale
     backend: str = "kernel",
     interpret: bool | None = None,
+    layer=None,              # traced int32: g_* are (L, S, P, rows, N)
 ) -> jax.Array:
     """Fused parasitic analog MVM chain (per-bit Thomas solve + analog
     bit accumulation + ADC + dequant in one launch); dequantized (M, N).
     The kernel and its oracle read the activations rows-major
-    ``(P, rows, M)`` (see ``kernels.bitline``)."""
+    ``(P, rows, M)`` (see ``kernels.bitline``).  ``layer`` as in
+    :func:`fused_mvm`."""
     if backend not in ("kernel", "oracle"):
         raise ValueError(f"unknown fused_mvm_parasitic backend {backend!r}")
+    g_pos, g_neg, layer = _stack_or_slice(g_pos, g_neg, layer, backend)
     interpret = _use_interpret() if interpret is None else interpret
     m, p, rows = x_parts.shape
     n = g_pos.shape[-1]
     bm = _pick_tile(m, 128)
     bn = _pick_tile(n, 128, lane=True)
     xt = _pad_to(jnp.transpose(x_parts, (1, 2, 0)).astype(jnp.float32), 2, bm)
-    gp = _pad_to(g_pos.astype(jnp.float32), 3, bn)
-    gm = _pad_to(g_neg.astype(jnp.float32), 3, bn)
+    gp = _pad_to(g_pos.astype(jnp.float32), g_pos.ndim - 1, bn)
+    gm = _pad_to(g_neg.astype(jnp.float32), g_neg.ndim - 1, bn)
     if backend == "oracle":
         out = _k_ref.fused_mvm_parasitic(
             xt, gp, gm, r_hat, adc_lo, adc_hi, scale,
@@ -271,7 +306,7 @@ def fused_mvm_parasitic(
         out = _k_fused.fused_mvm_parasitic_pallas(
             xt, gp, gm, r_hat, adc_lo, adc_hi, scale,
             adc_bits=adc_bits, cell_bits=cell_bits, n_bits=n_bits,
-            bm=bm, bn=bn, interpret=interpret,
+            bm=bm, bn=bn, interpret=interpret, layer=layer,
         )
     return out[:m, :n]
 

@@ -32,8 +32,9 @@ from repro.hw.profile import SiteSpecs
 class AnalogCtx:
     """Per-layer analog execution context threaded through blocks.
 
-    ``weights[name]`` is the :class:`AnalogWeights` for this layer (already
-    sliced out of the layer-stacked pack by the scan), ``lo/hi[name]`` the
+    ``weights[name]`` is the :class:`AnalogWeights` for this layer (sliced
+    out of the layer-stacked pack by the scan, or the whole stack and the
+    layer's index where the fused kernel reads it in place), ``lo/hi[name]`` the
     calibrated per-slice ADC limits, ``act[name]`` the activation clip.
     ``specs`` carries the *site-resolved* spec per hook name (heterogeneous
     profiles: attention and MLP projections may sit on different hardware;

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.config import ModelConfig
-from repro.core.analog import AnalogSpec, AnalogWeights, analog_matmul
+from repro.core.analog import (AnalogSpec, AnalogWeights, analog_matmul,
+                               reads_stacked)
 from repro.hw.profile import Profile, SiteSpecs
 from repro.models import ssm as ssm_mod
 from repro.models.attention import attention_block, init_attention
@@ -221,13 +222,33 @@ def _block(
     return x, {"attn": new_kv}, aux
 
 
-def _make_actx(pack: Optional[AnalogPack], sliced,
-               band: int) -> Optional[AnalogCtx]:
+def _stacked_sites(pack: AnalogPack) -> Dict[str, AnalogWeights]:
+    """The sites whose layer stacks the fused kernel reads in place in
+    every band that serves them analog (``core.analog.reads_stacked``):
+    the layer scan hands these over whole, with the layer's index, and
+    slices only the others."""
+    out = {}
+    for name, aw in pack.layer_weights.items():
+        specs = [s for s in (ss.get(name) for ss in pack.band_specs)
+                 if s is not None]
+        if specs and all(reads_stacked(s, aw, collect=pack.collect)
+                         for s in specs):
+            out[name] = aw
+    return out
+
+
+def _make_actx(pack: Optional[AnalogPack], sliced, band: int,
+               stacks: Dict[str, AnalogWeights],
+               layer) -> Optional[AnalogCtx]:
     """Band-resolved per-layer context: only sites analog in this band
-    are routed through the analog pipeline (the rest run digitally)."""
+    are routed through the analog pipeline (the rest run digitally).
+    A site in ``stacks`` gets its whole stack and the index ``layer``."""
     if pack is None:
         return None
     w, lo, hi, act = sliced
+    w = {**w, **{n: dataclasses.replace(w[n], g_pos=aw.g_pos,
+                                         g_neg=aw.g_neg, layer=layer)
+                 for n, aw in stacks.items()}}
     ss = pack.band_specs[band]
     names = ss.names
     return AnalogCtx(
@@ -282,13 +303,20 @@ def _scan_layers(
         xs["w"] = windows
     if cache is not None:
         xs["c"] = cache
+    stacks = _stacked_sites(pack) if pack is not None else {}
     if pack is not None:
-        xs["a"] = (pack.layer_weights, pack.layer_lo, pack.layer_hi,
-                   pack.layer_act)
+        # a stacked site's conductances stay out of the scanned slices
+        weights = {n: dataclasses.replace(aw, g_pos=None, g_neg=None)
+                   if n in stacks else aw
+                   for n, aw in pack.layer_weights.items()}
+        xs["a"] = (weights, pack.layer_lo, pack.layer_hi, pack.layer_act)
+    if stacks:
+        xs["l"] = jnp.arange(cfg.n_layers, dtype=jnp.int32)
 
     def band_scan(x, xs_band, band: int):
         def body(x, xs_l):
-            actx = _make_actx(pack, xs_l.get("a"), band) \
+            actx = _make_actx(pack, xs_l.get("a"), band, stacks,
+                              xs_l.get("l")) \
                 if pack is not None else None
             window = xs_l.get("w")
             x, new_cache, aux = _block(

@@ -520,6 +520,141 @@ def test_fused_mvm_parasitic_matches_oracle(m, p, s, rows, n):
 
 
 # ---------------------------------------------------------------------------
+# layer-stacked conductances: the kernel picks its layer from a scalar-
+# prefetched index in place of a per-layer slice written out before it
+# ---------------------------------------------------------------------------
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of ``jaxpr``, inner jaxprs included."""
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            out.append(e)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out.extend(_pallas_calls(inner))
+    return out
+
+
+def _stacked_tags(f, *args):
+    return [dict(e.params["metadata"])["stacked"]
+            for e in _pallas_calls(jax.make_jaxpr(f)(*args).jaxpr)]
+
+
+def _stacked_case(s, rows, n, *, layers=3, m=8, p=2, seed=0,
+                  dtype=jnp.float32):
+    x, _, _, lo, hi = _fused_case(m, p, s, rows, n, seed=seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 2)
+    gp = (jax.random.uniform(ks[0], (layers, s, p, rows, n)) * 0.1
+          ).astype(dtype)
+    gm = (jax.random.uniform(ks[1], (layers, s, p, rows, n)) * 0.1
+          ).astype(dtype)
+    return x, gp, gm, lo, hi
+
+
+@pytest.mark.parametrize("s", [1, 4])
+@pytest.mark.parametrize("n_bits", [None, 7], ids=["analog", "digital"])
+def test_fused_mvm_stacked_picks_its_layer_bitwise(s, n_bits):
+    """Handed a ``(L, S, P, rows, N)`` stack and a traced layer index, the
+    kernel gives, bit for bit, what it gives on that layer's slice: the
+    same blocks, grid and arithmetic, addressed through the index.  One
+    compilation serves every index, and the call says it read the stack
+    (``"stacked": true``) where the sliced one says it did not."""
+    x, gp, gm, lo, hi = _stacked_case(s, 64, 128, seed=s * 7)
+    kw = dict(adc_lo=lo, adc_hi=hi, adc_bits=8, cell_bits=2 if s > 1 else 7,
+              n_bits=n_bits, scale=jnp.float32(3e-4))
+    traces = []
+
+    def stacked(x, gp, gm, layer):
+        traces.append(layer)
+        return ops.fused_mvm(x, gp, gm, layer=layer, **kw)
+
+    f_stacked = jax.jit(stacked)
+    f_sliced = jax.jit(lambda x, gp, gm: ops.fused_mvm(x, gp, gm, **kw))
+    for layer in range(gp.shape[0]):
+        np.testing.assert_array_equal(
+            np.asarray(f_stacked(x, gp, gm, jnp.int32(layer))),
+            np.asarray(f_sliced(x, gp[layer], gm[layer])))
+    assert len(traces) == 1
+    assert _stacked_tags(stacked, x, gp, gm, jnp.int32(0)) == [True]
+    assert _stacked_tags(lambda *a: ops.fused_mvm(*a, **kw),
+                         x, gp[0], gm[0]) == [False]
+
+
+def test_fused_mvm_parasitic_stacked_picks_its_layer_bitwise():
+    """The parasitic kernel shares the call's plumbing, so it reads a
+    stack by its index too, with ``r_hat`` traced beside it."""
+    x, gp, gm, lo, hi = _stacked_case(2, 24, 128, p=1, m=4, seed=5)
+    x = jnp.clip(x, -127, 127)
+    kw = dict(adc_lo=lo, adc_hi=hi, adc_bits=8, cell_bits=2, n_bits=7,
+              scale=jnp.float32(3e-4))
+    f_stacked = jax.jit(lambda r, layer: ops.fused_mvm_parasitic(
+        x, gp, gm, r_hat=r, layer=layer, **kw))
+    f_sliced = jax.jit(lambda r, g_p, g_m: ops.fused_mvm_parasitic(
+        x, g_p, g_m, r_hat=r, **kw))
+    r = jnp.float32(1e-4)
+    for layer in range(gp.shape[0]):
+        np.testing.assert_array_equal(
+            np.asarray(f_stacked(r, jnp.int32(layer))),
+            np.asarray(f_sliced(r, gp[layer], gm[layer])))
+    assert _stacked_tags(f_stacked, r, jnp.int32(1)) == [True]
+
+
+@pytest.mark.parametrize("rows,dtype", [(854, jnp.float32),
+                                        (64, jnp.bfloat16)],
+                         ids=["rows-854", "bfloat16"])
+def test_fused_mvm_stack_it_cannot_read_as_stored_is_sliced(rows, dtype):
+    """A stack the kernel cannot read as stored (854 rows, which the TPU
+    tiles ``(1, 128)``; bfloat16, which it would convert) is sliced to
+    the layer before a per-layer call, ``"stacked": false``, with the
+    same result; the oracle always slices."""
+    x, gp, gm, lo, hi = _stacked_case(1, rows, 128, p=1, seed=rows,
+                                      dtype=dtype)
+    assert not ops.fused_reads_stacked(gp)
+    kw = dict(adc_lo=lo, adc_hi=hi, adc_bits=8, cell_bits=7, n_bits=None,
+              scale=jnp.float32(3e-4))
+    layer = jnp.int32(2)
+    stacked = jax.jit(lambda l: ops.fused_mvm(x, gp, gm, layer=l, **kw))
+    sliced = jax.jit(lambda g_p, g_m, backend: ops.fused_mvm(
+        x, g_p, g_m, backend=backend, **kw), static_argnums=2)
+    np.testing.assert_array_equal(np.asarray(stacked(layer)),
+                                  np.asarray(sliced(gp[2], gm[2], "kernel")))
+    assert _stacked_tags(stacked, layer) == [False]
+    oracle = jax.jit(lambda l: ops.fused_mvm(x, gp, gm, layer=l,
+                                             backend="oracle", **kw))
+    np.testing.assert_array_equal(np.asarray(oracle(layer)),
+                                  np.asarray(sliced(gp[2], gm[2], "oracle")))
+
+
+def test_fused_mvm_stacked_under_vmap_batches_the_per_layer_call():
+    """Under ``vmap`` over trials (each its own stack and dequant scale,
+    the layer shared), the stacked call becomes the per-layer call on
+    the layer's slice, batched by Pallas in its grid, not a loop over
+    trials that would slice every trial's whole stack; the bits are the
+    per-trial calls'."""
+    x, gp, gm, lo, hi = _stacked_case(1, 64, 128, seed=9)
+    gp2, gm2 = jnp.stack([gp, gp * 0.5]), jnp.stack([gm, gm * 0.5])
+    scales = jnp.asarray([3e-4, 5e-4], jnp.float32)
+    kw = dict(adc_lo=lo, adc_hi=hi, adc_bits=8, cell_bits=7, n_bits=None)
+    layer = jnp.int32(1)
+    f = jax.jit(jax.vmap(lambda g_p, g_m, sc: ops.fused_mvm(
+        x, g_p, g_m, scale=sc, layer=layer, **kw)))
+    y = f(gp2, gm2, scales)
+    sliced = jax.jit(lambda g_p, g_m, sc: ops.fused_mvm(x, g_p, g_m, scale=sc,
+                                                        **kw))
+    for t in range(2):
+        np.testing.assert_array_equal(
+            np.asarray(y[t]), np.asarray(sliced(gp2[t, 1], gm2[t, 1],
+                                                scales[t])))
+    jaxpr = jax.make_jaxpr(f)(gp2, gm2, scales).jaxpr
+    calls = _pallas_calls(jaxpr)
+    assert [dict(e.params["metadata"])["stacked"] for e in calls] == [False]
+    assert "while" not in str(jaxpr)
+
+
+# ---------------------------------------------------------------------------
 # flash-decode attention over the dense per-slot KV cache
 # ---------------------------------------------------------------------------
 

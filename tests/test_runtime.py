@@ -8,6 +8,8 @@ pure function of the per-request key (stable uid hash, never admission
 order), mirroring how programming keys fold from stable hook-name
 hashes (``tests/test_serve_engine.py``)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -352,6 +354,117 @@ def test_jitted_steps_take_weights_as_arguments(lm, paged):
     consts = _captured_consts(closed)
     assert all(np.size(c) < smallest for c in consts), \
         [np.shape(c) for c in consts]
+
+
+# ---------------------------------------------------------------------------
+# sites whose layer stacks the fused kernel reads in place
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def mixed_stack_lm():
+    """A smoke LM at lane-wide widths whose fused sites mix the two kinds:
+    ``max_rows=100`` splits K = 128 into 2 partitions of 64 rows (a
+    multiple of 8, so the kernel reads those stacks in place) and
+    K = 256 (``w_down``) into 3 of 86 (not, so it is sliced per layer)."""
+    cfg = dataclasses.replace(
+        get_smoke_config("qwen1.5-4b"), d_model=128, n_heads=2,
+        n_kv_heads=2, d_ff=256, vocab=128, n_layers=2)
+    params = get_model(cfg).init_params(cfg, jax.random.PRNGKey(0))
+    spec = dataclasses.replace(
+        A.design_a(error=E.state_proportional(0.02), fused="kernel"),
+        max_rows=100)
+    pack = program_lm(cfg, params, spec, jax.random.PRNGKey(5))
+    pack = dataclasses.replace(calibrate_lm(
+        cfg, params, pack, jnp.arange(64).reshape(2, 32) % cfg.vocab),
+        collect=False)
+    return cfg, params, spec, pack
+
+
+def test_mixed_stack_lm_mixes_stacked_and_sliced_sites(mixed_stack_lm):
+    """The layer scan hands over whole the stacks the kernel reads as
+    stored, and no stack of an oracle pack or of calibration."""
+    from repro.sweep.serve_eval import pack_with_fused
+
+    _, _, _, pack = mixed_stack_lm
+    assert set(transformer._stacked_sites(pack)) == {
+        "wq", "wk", "wv", "wo", "w_gate", "w_up"}
+    assert transformer._stacked_sites(pack_with_fused(pack, "oracle")) == {}
+    assert transformer._stacked_sites(
+        dataclasses.replace(pack, collect=True)) == {}
+
+
+def _kernel_and_oracle_tokens(cfg, params, pack, paged):
+    from repro.serve import PagedServeRuntime
+    from repro.sweep.serve_eval import pack_with_fused
+
+    reqs = _trace(cfg, 5, seed=3, lens=(4, 9), new=(3, 6))
+    outs = []
+    for mode in ("kernel", "oracle"):
+        kw = dict(pack=pack_with_fused(pack, mode), max_slots=2, max_len=16)
+        rt = (PagedServeRuntime(cfg, params, page_size=4, **kw) if paged
+              else ServeRuntime(cfg, params, **kw))
+        for i, (p, n) in enumerate(reqs):
+            rt.submit(p, max_new_tokens=n, uid=f"r{i}")
+        outs.append(rt.run())
+    return outs
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_stacked_conductances_change_no_token(mixed_stack_lm, paged):
+    """Serving a pack whose stacks the kernel reads by the layer's index
+    says, token for token, what the same pack says at ``fused="oracle"``,
+    which slices every layer out of the stack, through ``ServeRuntime``
+    and through ``PagedServeRuntime``, which shares the layer scan."""
+    cfg, params, _, pack = mixed_stack_lm
+    kernel, oracle = _kernel_and_oracle_tokens(cfg, params, pack, paged)
+    assert sorted(kernel) == sorted(oracle)
+    for uid in oracle:
+        np.testing.assert_array_equal(kernel[uid], oracle[uid])
+
+
+def test_stacked_conductances_of_a_banded_pack_change_no_token(
+        mixed_stack_lm):
+    """A pack of two layer bands (8- and 6-bit ADCs) scans each band
+    separately; a stacked site stays whole across both, indexed by its
+    absolute layer, and serves what the oracle serves."""
+    from repro.hw import Profile, Rule
+
+    cfg, params, spec, _ = mixed_stack_lm
+    spec6 = dataclasses.replace(
+        spec, adc=dataclasses.replace(spec.adc, bits=6))
+    profile = Profile(rules=(Rule("*", spec, layers=(0, 1)),
+                             Rule("*", spec6, layers=(1, 2))), default=spec)
+    pack = program_lm(cfg, params, profile, jax.random.PRNGKey(5))
+    pack = dataclasses.replace(calibrate_lm(
+        cfg, params, pack, jnp.arange(64).reshape(2, 32) % cfg.vocab),
+        collect=False)
+    assert len(pack.bands) == 2
+    assert "wq" in transformer._stacked_sites(pack)
+    kernel, oracle = _kernel_and_oracle_tokens(cfg, params, pack, False)
+    for uid in oracle:
+        np.testing.assert_array_equal(kernel[uid], oracle[uid])
+
+
+def test_sweep_point_with_stacked_sites_matches_the_oracle(mixed_stack_lm):
+    """One trial-vmapped ``ServeEvaluator`` point: under ``vmap`` each
+    stacked call becomes the per-layer call Pallas batches, and the
+    point's metrics are the oracle's (its greedy decode token for token,
+    its loss to float32 rounding)."""
+    from repro.sweep import ServeEvaluator
+
+    cfg, params, spec, _ = mixed_stack_lm
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0, cfg.vocab)
+    ev = ServeEvaluator(cfg, params, toks, toks, toks, prompts=toks[:, :4],
+                        decode_new=3, include_head=False)
+    got = {mode: ev.evaluate_group(dataclasses.replace(spec, fused=mode),
+                                   ("error.alpha",), [(0.02,)], 2, 0, None)
+           for mode in ("kernel", "oracle")}
+    for k_trial, o_trial in zip(got["kernel"][0], got["oracle"][0]):
+        assert k_trial["decode_match"] == o_trial["decode_match"]
+        assert k_trial["top1"] == o_trial["top1"]
+        np.testing.assert_allclose(k_trial["loss"], o_trial["loss"],
+                                   rtol=1e-6)
 
 
 def test_cache_slot_insert_and_evict(lm):

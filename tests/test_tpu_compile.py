@@ -20,6 +20,7 @@ at import, so that only the test worker given this file loads the TPU
 compiler.
 """
 
+import dataclasses
 import os
 import re
 
@@ -139,6 +140,32 @@ def test_fused_mvm_parasitic_compiles(one_chip, n_slices):
                    f"('parasitic', {n_slices}, {cell_bits}, 8, None, 7)")
 
 
+@pytest.mark.parametrize("parasitic", [False, True],
+                         ids=["linear", "parasitic"])
+def test_fused_mvm_compiles_reading_a_layer_stack(one_chip, parasitic):
+    """Both fused kernels read a 4-layer stack of 1024-row partitions
+    (K = 5120) by a traced layer index: Mosaic takes the scale and the
+    index as scalar-prefetch operands, the scale first, and the call is
+    tagged ``"stacked": true``."""
+    _, rows, shapes = _fused_shapes(5120, 256, 1)
+    assert rows == 1024
+    shapes[1:3] = [((4,) + shapes[1][0], jnp.float32)] * 2
+    shapes.append(((), jnp.int32))
+
+    def f(x, gp, gm, lo, hi, scale, layer):
+        kw = dict(adc_lo=lo, adc_hi=hi, adc_bits=8, cell_bits=7,
+                  scale=scale, interpret=False, layer=layer)
+        if parasitic:
+            return ops.fused_mvm_parasitic(x, gp, gm, r_hat=jnp.float32(1e-4),
+                                           n_bits=7, **kw)
+        return ops.fused_mvm(x, gp, gm, n_bits=None, **kw)
+
+    text = _compile_text(f, shapes, one_chip)
+    _assert_tagged(text, "fused_mvm")
+    assert [st for _, st in _fused_mvm_calls(text)] == [True]
+    _assert_fused_calls_read_as_the_benchmark_finds_them(text, 1)
+
+
 def test_bitline_mvm_compiles(one_chip):
     rows = design_a().rows_per_partition(D_MODEL)
 
@@ -234,30 +261,21 @@ def test_paged_attention_compiles(one_chip):
     _assert_tagged(text, "paged_attention")
 
 
-def test_qwen3_decode_step_fits_and_reads_the_cache_row_major(one_chip,
-                                                              monkeypatch):
-    """The ``qwen3-14b.chat`` cell's whole decode step, as
-    ``ServeRuntime`` jits it: Qwen3-14B's published widths (5120, 40 query
-    heads over 8 KV heads of 128, d_ff 17408, per-head q/k norm) on 4
-    layers with an eighth of the padded vocabulary (19072), a Design A
-    pack, 16 slots of 768 bfloat16 positions.  What the chip holds while
-    it runs (every weight and conductance, the slot state, the step's
-    output and temporaries) fits its 16 GiB; flash-decode reads the
-    8-head cache row-major, 5 queries to a KV head; and no copy,
-    convert or transpose of a layer's cache, or of the stacked cache, is
-    made outside the kernel."""
+def _decode_step(cfg, one_chip, monkeypatch):
+    """``ServeRuntime``'s jitted decode step for ``cfg`` with a Design A
+    pack, 16 slots of 768 bfloat16 positions, compiled for one described
+    v5e chip: ``(compiled text, bytes held while it runs, pack)``.  What
+    the chip holds is every weight and conductance, the slot state, the
+    step's output and its temporaries."""
     import dataclasses
 
-    from repro.configs.qwen3_14b import CONFIG
     from repro.core.errors import ErrorModel
     from repro.models.registry import get_model
     from repro.serve import ServeRuntime, calibrate_lm, program_lm
 
     monkeypatch.setattr(ops, "_use_interpret", lambda: False)
-    cfg = dataclasses.replace(CONFIG, n_layers=4, vocab=19072, remat=False)
     spec = design_a(ErrorModel(kind="state_proportional", alpha=0.02),
                     fused="kernel")
-    slots, max_len = 16, 768
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     params = jax.eval_shape(
         lambda k: jax.tree.map(lambda a: a.astype(cfg.dtype),
@@ -268,9 +286,8 @@ def test_qwen3_decode_step_fits_and_reads_the_cache_row_major(one_chip,
         lambda p, pk, t: dataclasses.replace(calibrate_lm(cfg, p, pk, t),
                                              collect=False),
         params, pack, jax.ShapeDtypeStruct((2, 64), jnp.int32))
-    rt = ServeRuntime(cfg, params, pack=pack, max_slots=slots,
-                      max_len=max_len, buckets=(128, 256),
-                      attn_backend="flash")
+    rt = ServeRuntime(cfg, params, pack=pack, max_slots=16, max_len=768,
+                      buckets=(128, 256), attn_backend="flash")
     on_chip = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
     state = on_chip(jax.eval_shape(lambda: rt._state))
@@ -282,9 +299,68 @@ def test_qwen3_decode_step_fits_and_reads_the_cache_row_major(one_chip,
     mem = compiled.memory_analysis()
     held = (nbytes(params) + nbytes(pack) + nbytes(state)
             + mem.output_size_in_bytes + mem.temp_size_in_bytes)
+    return compiled.as_text(), held, pack
+
+
+def _fused_mvm_calls(text: str):
+    """``(operand shapes, stacked)`` of every fused MVM call in ``text``:
+    the shapes as its ``operand_layout_constraints`` list them, in
+    operand order, and its ``"stacked"`` tag."""
+    calls = re.findall(r"%fused_mvm\.\d+ = [^\n]*custom-call\([^\n]*"
+                       r"operand_layout_constraints=\{((?:\w+\[[\d,]*\]\{[\d,]*\}(?:, )?)*)\}"
+                       r"[^\n]*"
+                       r"kernel_metadata=\{\n(.*?)\n\}\}", text, re.S)
+    out = []
+    for operands, meta in calls:
+        stacked = re.search(r'"stacked":(true|false)', meta)
+        assert stacked, meta
+        out.append((re.findall(r"\w+\[[\d,]*\]", operands),
+                    stacked[1] == "true"))
+    return out
+
+
+def _ops_of_shape(text: str, keep):
+    """``(line, name, opcode)`` of every instruction whose shape ``keep``
+    takes."""
+    for line in text.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                      line)
+        if op and keep(tuple(int(d) for d in op[2].split(",") if d)):
+            yield line, op[1], op[3]
+
+
+def _assert_fused_calls_read_as_the_benchmark_finds_them(text, n_calls):
+    """Every fused MVM call leads with its (1, 1) float32 dequant scale,
+    the operand by which ``chipbench.readers.FUSED_MVM_OPS`` tells it from
+    flash-decode (whose int32 fills lead), stacked or not."""
+    calls = _fused_mvm_calls(text)
+    assert len(calls) == n_calls, calls
+    assert all(operands[0] == "f32[1,1]" for operands, _ in calls), calls
+    return calls
+
+
+def test_qwen3_decode_step_fits_and_reads_the_cache_row_major(one_chip,
+                                                              monkeypatch):
+    """The ``qwen3-14b.chat`` cell's whole decode step, as
+    ``ServeRuntime`` jits it: Qwen3-14B's published widths (5120, 40 query
+    heads over 8 KV heads of 128, d_ff 17408, per-head q/k norm) on 4
+    layers with an eighth of the padded vocabulary (19072), a Design A
+    pack, 16 slots of 768 bfloat16 positions.  What the chip holds while
+    it runs fits its 16 GiB; flash-decode reads the 8-head cache
+    row-major, 5 queries to a KV head; no copy, convert or transpose of a
+    layer's cache, or of the stacked cache, is made outside the kernel.
+    Every layer site's partitions (1024 and 1088 rows) are read by the
+    kernel in the stacked pack, by the layer's index: each of the 7
+    layer-site calls is ``"stacked": true`` (the head's is not), and no
+    slice, copy or relayout of any site's conductances, one layer's or
+    the stack's, is written out before a kernel."""
+    from repro.configs.qwen3_14b import CONFIG
+
+    cfg = dataclasses.replace(CONFIG, n_layers=4, vocab=19072, remat=False)
+    slots, max_len = 16, 768
+    text, held, pack = _decode_step(cfg, one_chip, monkeypatch)
     assert held < 16 * 2 ** 30, held
 
-    text = compiled.as_text()
     calls = _tagged_calls(text)
     flash = [meta for name, meta in calls if name.startswith("flash_decode.")]
     assert {name.split(".")[0] for name, _ in calls} == {"flash_decode",
@@ -293,11 +369,46 @@ def test_qwen3_decode_step_fits_and_reads_the_cache_row_major(one_chip,
                and '"group":5' in m for m in flash), flash
     layer = sorted((slots, max_len, cfg.n_kv_heads, cfg.hd))
     stacked = sorted(layer + [cfg.n_layers])
-    for line in text.splitlines():
-        op = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* (\w+)\(",
-                      line)
-        if not op or sorted(int(d) for d in op[2].split(",") if d) not in (
-                layer, stacked):
-            continue
-        assert not re.match(r"(copy|convert|transpose)", op[1]), line
-        assert op[3] not in ("copy", "convert", "transpose"), line
+    cache = lambda d: sorted(d) in (layer, stacked)
+    for line, name, opcode in _ops_of_shape(text, cache):
+        assert not re.match(r"(copy|convert|transpose)", name), line
+        assert opcode not in ("copy", "convert", "transpose"), line
+
+    fused = _assert_fused_calls_read_as_the_benchmark_finds_them(text, 8)
+    assert sorted(st for _, st in fused) == [False] + [True] * 7
+    sites = {aw.g_pos.shape[-3:] for aw in pack.layer_weights.values()}
+    conductance = lambda d: (len(d) >= 4 and d[-3:] in sites
+                             and d[:-3] in ((1,), (1, 1), (cfg.n_layers, 1)))
+    for line, _, opcode in _ops_of_shape(text, conductance):
+        assert opcode in ("parameter", "get-tuple-element"), line
+
+
+def test_qwen1_5_decode_step_reads_only_its_1152_row_stack_in_place(
+        one_chip, monkeypatch):
+    """The ``qwen1.5-4b.chat`` cell's decode step (published widths, 8
+    layers, the whole vocabulary, 16 slots of 768 positions).  Its
+    ``w_down`` partitions have 1152 rows, so the kernel reads that stack
+    by the layer's index (``"stacked": true`` on that call alone); the
+    other six sites have 854 rows, which the TPU tiles ``(1, 128)``, and
+    keep their per-layer slice.  Nothing relays out, copies or converts
+    a whole stack (a shape led by the 8 layers), which handing such a
+    stack to the kernel would do, and what the chip holds fits 16 GiB."""
+    from repro.configs.qwen1_5_4b import CONFIG
+
+    cfg = dataclasses.replace(CONFIG, n_layers=8, remat=False)
+    text, held, pack = _decode_step(cfg, one_chip, monkeypatch)
+    assert held < 16 * 2 ** 30, held
+
+    fused = _assert_fused_calls_read_as_the_benchmark_finds_them(text, 8)
+    down = pack.layer_weights["w_down"].g_pos.shape
+    assert down[-2] == 1152
+    stacked = [operands for operands, st in fused if st]
+    assert len(stacked) == 1
+    assert f"f32[{','.join(map(str, down))}]" in stacked[0], stacked
+    sites = {aw.g_pos.shape[-3:] for aw in pack.layer_weights.values()}
+    whole = lambda d: len(d) >= 4 and d[-3:] in sites and d[0] == cfg.n_layers
+    for line, _, opcode in _ops_of_shape(text, whole):
+        assert opcode in ("parameter", "get-tuple-element"), line
+    one_down = lambda d: d in (down[1:], (1,) + down[1:])
+    for line, _, opcode in _ops_of_shape(text, one_down):
+        assert opcode not in ("copy", "dynamic-slice", "fusion"), line
